@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import traffic
+from repro import compile_cache
 from repro.core import autotune, ir, models, mwd, registry, stencils as st
 from repro.core.mwd import MWDPlan
 from repro.kernels import ops
@@ -155,8 +156,7 @@ def fig16_18_groupsize():
         for tg in (1, 2, 4, 8, 16):
             score = autotune.model_score(spec, grid)(
                 MWDPlan(d_w=32 if spec.radius == 1 else 32, n_f=2, tg_x=tg))
-            n_xb = grid[2] // tg * 4 * spec.bytes_per_cell
-            fits = models.vmem_fits(spec, 32, 2, n_xb)
+            fits = models.vmem_fits(spec, 32, 2, grid[2] // tg)
             _row(f"groupsize.{name}.tg{tg}", 0.0,
                  f"model_GLUPs_dev={score:.1f};vmem_fits_dw32={fits}")
 
@@ -629,6 +629,7 @@ BENCHES = {
 
 
 def main() -> None:
+    compile_cache.enable()
     only = sys.argv[1] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")
     for name, fn in BENCHES.items():

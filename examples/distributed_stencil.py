@@ -14,7 +14,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax                                                   # noqa: E402
 import jax.numpy as jnp                                      # noqa: E402
 
-from repro import compat                                     # noqa: E402
 from repro.core import stencils as st                        # noqa: E402
 from repro.distributed import checkpoint, stepper            # noqa: E402
 
@@ -28,7 +27,8 @@ def main():
     # phase 1: healthy 2x2x2 mesh (2 pods); overlap="auto" runs the
     # interior/boundary-split schedule (bitwise-equal to synchronous) where
     # the shards have room, and falls back to synchronous where not
-    mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 3)
     out = stepper.run_distributed(spec, mesh, state, coeffs, T1, t_block=2,
                                   overlap="auto")
     ckpt_dir = "/tmp/dist_stencil_ckpt"
@@ -36,7 +36,8 @@ def main():
     print(f"phase 1: {T1} steps on {mesh.devices.size} devices, checkpointed")
 
     # phase 2: a pod dies -> rebuild on 4 devices, reshard, continue
-    small = compat.make_mesh((2, 2), ("data", "model"),
+    small = jax.make_mesh((2, 2), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
                              devices=jax.devices()[:4])
     gs = stepper.GridSharding(small)
     _, restored = checkpoint.restore(

@@ -1,68 +1,7 @@
-"""JAX version-compatibility shims.
-
-The repo supports the jax range declared in pyproject.toml; a handful of
-sharding APIs moved or were renamed across that range. Everything
-version-sensitive goes through here so the rest of the codebase (and CI,
-which installs the newest allowed jax) stays clean.
-"""
+"""Portable plan translation across device specs."""
 
 from __future__ import annotations
 
-import jax
-
-try:                                    # jax >= 0.5 exports it at top level
-    shard_map = jax.shard_map
-except AttributeError:                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """jax.make_mesh with explicit Auto axis_types where supported."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-def get_abstract_mesh():
-    """Current mesh context, or None — callers treat None as 'no mesh'."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    try:                                # jax 0.4.x: thread-local physical mesh
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        return None
-
-
-def set_mesh(mesh):
-    """Context manager activating `mesh` for sharding-context lookups.
-
-    jax >= 0.7 spells it jax.set_mesh, 0.5-0.6 jax.sharding.use_mesh; on
-    0.4.x the Mesh object is itself the context manager (it sets the
-    thread-local physical mesh that get_abstract_mesh()'s fallback reads).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
-def axis_size(axis_name) -> int:
-    """jax.lax.axis_size where available (jax >= 0.5); psum(1) fallback."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# ---------------------------------------------------------------------------
-# Portable plan translation across device specs
-# ---------------------------------------------------------------------------
 
 def translate_entry(entry, op, grid_shape, *, to_spec, word_bytes=4, batch=1):
     """Translate a registry entry tuned under another spec to `to_spec`.
@@ -81,10 +20,8 @@ def translate_entry(entry, op, grid_shape, *, to_spec, word_bytes=4, batch=1):
          under the two machine models. No re-measurement happens.
 
     The returned entry carries ``source="translated:<spec A>"``, the target
-    spec's name/fingerprint, and the rescaled score. Lives here (not in
-    core.registry) because it is a cross-version/cross-machine adaptation
-    concern, like the jax shims above; imports are deferred so importing
-    repro.compat stays jax-light.
+    spec's name/fingerprint, and the rescaled score. Imports are deferred
+    so importing repro.compat stays jax-light.
     """
     import dataclasses
     import math
@@ -100,9 +37,9 @@ def translate_entry(entry, op, grid_shape, *, to_spec, word_bytes=4, batch=1):
     plan = entry.plan
     if not autotune._plan_valid(op, plan):
         return None
-    nz, ny, nx = grid_shape
-    n_xb = (nx // plan.tg_x) * word_bytes * op.bytes_per_cell
-    if not models.vmem_fits(op, plan.d_w, plan.n_f, n_xb, to_spec):
+    nx = grid_shape[2]
+    if not models.vmem_fits(op, plan.d_w, plan.n_f, nx // plan.tg_x, to_spec,
+                            word_bytes):
         return None
     score_a = autotune.model_score(op, grid_shape, word_bytes, from_spec,
                                    batch)(plan)

@@ -4,7 +4,7 @@ Flow, mirroring the paper:
   1. enumerate feasible thread-group factorizations (here: device-group sizes
      tg_x that divide the devices available along x);
   2. for each, local-search hill-climb over (D_w, N_F) seeded at the largest
-     D_w whose VMEM footprint fits (Eq. 3 prunes the space);
+     D_w whose VMEM footprint (`models.mwd_vmem_bytes`) fits;
   3. score with an injected measure() callback — wall-clock on hardware, the
      ECM/roofline model in dry-run mode (this container).
 
@@ -65,8 +65,8 @@ def model_score(spec: StencilSpec, grid_shape, word_bytes: int = 4,
     def score(plan: MWDPlan) -> float:
         if not _plan_valid(spec, plan):
             return -math.inf
-        n_xb = (nx // plan.tg_x) * word_bytes * spec.bytes_per_cell
-        if not models.vmem_fits(spec, plan.d_w, plan.n_f, n_xb, chip):
+        if not models.vmem_fits(spec, plan.d_w, plan.n_f, nx // plan.tg_x,
+                                chip, word_bytes):
             return -math.inf
         bc = models.code_balance(spec, plan.d_w, word_bytes)
         lups = nz * ny * (nx // plan.tg_x)
@@ -228,8 +228,8 @@ def measure_score(spec: StencilSpec, grid_shape, word_bytes: int = 4,
         nx_l = nx // plan.tg_x
         if nx_l <= 2 * spec.radius:
             return -math.inf               # no interior left on this device
-        n_xb = nx_l * word_bytes * spec.bytes_per_cell
-        if not models.vmem_fits(spec, plan.d_w, plan.n_f, n_xb, chip):
+        if not models.vmem_fits(spec, plan.d_w, plan.n_f, nx_l, chip,
+                                word_bytes):
             return -math.inf
         if nx_l not in problems:
             probs = [st.make_problem(spec, (nz, ny, nx_l), dtype=dtype,
@@ -263,14 +263,14 @@ def _neighbors(plan: MWDPlan, radius: int,
     return cands
 
 
-def _seed_d_w(spec: StencilSpec, n_xb: int, chip: devspecs.DeviceSpec,
-              d_w_cap: int | None = None) -> int:
-    """Largest D_w fitting VMEM (Eq. 3) — the model-pruned starting point."""
+def _seed_d_w(spec: StencilSpec, nx: int, chip: devspecs.DeviceSpec,
+              d_w_cap: int | None = None, word_bytes: int = 4) -> int:
+    """Largest D_w fitting VMEM — the model-pruned starting point."""
     step = 2 * spec.radius
     cap = 4096 if d_w_cap is None else max(step, (d_w_cap // step) * step)
     d_w = step
-    while d_w + step <= cap and models.vmem_fits(spec, d_w + step, 1, n_xb,
-                                                 chip):
+    while d_w + step <= cap and models.vmem_fits(spec, d_w + step, 1, nx,
+                                                 chip, word_bytes):
         d_w += step
     return d_w
 
@@ -364,9 +364,8 @@ def autotune(spec: StencilSpec, grid_shape, devices_x: int = 1,
     # thread-group factorization (Fig. 7 step 2): tg_x over divisors
     tg_sizes = [d for d in range(1, devices_x + 1) if devices_x % d == 0]
     for tg in tg_sizes:
-        n_xb = (nx // tg) * word_bytes * spec.bytes_per_cell
-        seed = MWDPlan(d_w=_seed_d_w(spec, n_xb, chip, d_w_cap), n_f=1,
-                       tg_x=tg)
+        seed = MWDPlan(d_w=_seed_d_w(spec, nx // tg, chip, d_w_cap,
+                                     word_bytes), n_f=1, tg_x=tg)
         if is_measured:
             # cold start: let the free analytic model walk the seed to its
             # optimum before spending wall-clock measurements

@@ -298,17 +298,42 @@ class StencilOp:
 # Generated sweep (replaces the four hand-written bodies)
 # ---------------------------------------------------------------------------
 
+def update(op: StencilOp, tap, coeff, prev_core):
+    """The op's update expression over caller-supplied operand readers.
+
+    `tap(offset)` returns cur displaced by a tap offset, `coeff(c)` the
+    value of a coefficient source and `prev_core()` the previous level, all
+    over the same target cells.  The expression follows `op.groups`
+    exactly: per group, the taps are summed left-associatively in listed
+    order, multiplied once by the group coefficient, and accumulated across
+    groups in first-appearance order; a 2nd-order op wraps the
+    accumulation as ``2*V - prev [+ scale * acc]``.  `make_sweep` reads the
+    operands as shifted slices of whole arrays; the MWD kernel reads them
+    from its VMEM windows.
+    """
+    acc = None
+    for c, taps in op.groups:
+        s = None
+        for t in taps:
+            v = tap(t.offset)
+            s = v if s is None else s + v
+        term = coeff(c) * s
+        acc = term if acc is None else acc + term
+    if op.time_order == 2:
+        lead = 2.0 * tap((0, 0, 0)) - prev_core()
+        acc = lead + (coeff(op.scale) * acc if op.scale is not None
+                      else acc)
+    return acc
+
+
 @functools.lru_cache(maxsize=None)
 def make_sweep(op: StencilOp):
     """Generate the JAX sweep for `op`: ``(cur, prev, arrays, scalars) -> new``.
 
-    The generated expression follows `op.groups` exactly: per group, the taps
-    are summed left-associatively in listed order, multiplied once by the
-    group coefficient, and accumulated across groups in first-appearance
-    order; a 2nd-order op wraps the accumulation as
-    ``2*V - prev [+ scale * acc]``.  For the four paper operators this is
-    bitwise-equal to the hand-written listings (`repro.core.listings`),
-    which the property tests in tests/test_ir.py pin.
+    The interior is `update` over shifted slices.  For the four paper
+    operators this is bitwise-equal to the hand-written listings
+    (`repro.core.listings`), which the property tests in tests/test_ir.py
+    pin.
 
     `arrays` is the stacked ``(A, ...)`` coefficient stream (or None when the
     op has no array coefficients); `scalars` is indexable by slot (a tuple of
@@ -320,29 +345,17 @@ def make_sweep(op: StencilOp):
     def _core(a):
         return a[r:-r, r:-r, r:-r]
 
-    def _shift(a, off):
-        idx = tuple(slice(r + d, a.shape[ax] - r + d or None)
-                    for ax, d in enumerate(off))
-        return a[idx]
-
     def sweep(cur, prev, arrays, scalars):
+        def tap(off):
+            return cur[tuple(slice(r + d, cur.shape[ax] - r + d or None)
+                             for ax, d in enumerate(off))]
+
         def cval(c: Coeff):
             if c.kind == "const":
                 return scalars[c.index]
             return _core(arrays[c.index])
 
-        acc = None
-        for coeff, taps in op.groups:
-            s = None
-            for t in taps:
-                v = _shift(cur, t.offset)
-                s = v if s is None else s + v
-            term = cval(coeff) * s
-            acc = term if acc is None else acc + term
-        if op.time_order == 2:
-            lead = 2.0 * _core(cur) - _core(prev)
-            acc = lead + (cval(op.scale) * acc if op.scale is not None
-                          else acc)
+        acc = update(op, tap, cval, lambda: _core(prev))
         return cur.at[r:-r, r:-r, r:-r].set(acc)
 
     return sweep

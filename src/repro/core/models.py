@@ -48,19 +48,74 @@ def cache_block_bytes(spec: StencilSpec, d_w: int, n_f: int, n_xb: int) -> float
     return n_xb * (n_d * d_w * (d_w / 2.0 - r + n_f) + 2.0 * r * (d_w + w_w))
 
 
-def vmem_fits(spec: StencilSpec, d_w: int, n_f: int, n_xb: int,
-              chip: devspecs.DeviceSpec | None = None,
-              double_buffer: bool = True) -> bool:
-    """VMEM-fit constraint for the auto-tuner (Eq. 3).
+LANES = 128                 # TPU vector lane width (the x tile)
+COMPILER_RESERVE = 2 << 20  # VMEM bytes left to Mosaic's own scratch
 
-    Software-managed memory makes the footprint exact; `double_buffer` adds
-    2x the in/out DMA slab buffers the pipelined kernel keeps in flight.
+
+def sublanes(word_bytes: int) -> int:
+    """Rows of one (sublane, lane) VMEM tile: 8 for 32-bit words, 16 for 16-bit."""
+    return 8 * max(1, 4 // word_bytes)
+
+
+@dataclasses.dataclass(frozen=True)
+class MWDWindow:
+    """Tile-aligned VMEM window of the MWD kernel (`kernels/stencil_mwd`).
+
+    Along y a tile owns D_w rows that start at any offset below one sublane
+    tile ``s`` (the diamond rows alternate by D_w/2).  The kernel updates the
+    aligned `span` rows that cover them, reads `s` rows either side (R <= s)
+    and emits the whole span, so every DMA start and length along y is a
+    multiple of `s`.  x is padded to whole lanes, `nxp`.
+    """
+
+    s: int          # sublane tile rows
+    span: int       # updated / emitted rows per tile, a multiple of s
+    wy: int         # window rows: span + 2s
+    z_ws: int       # window z-rows: N_F + D_w + R
+    nxp: int        # lane-padded x extent
+
+
+def mwd_window(radius: int, d_w: int, n_f: int, nx: int,
+               word_bytes: int = DEFAULT_WORD_BYTES) -> MWDWindow:
+    """Aligned window geometry of one MWD tile (see `MWDWindow`).
+
+    Owned rows start at offsets ``k*D_w - e*D_w/2`` modulo `s` from an
+    aligned origin, i.e. at multiples of ``gcd(D_w/2, s)``, so the largest
+    offset is ``s - gcd(D_w/2, s)``.
+    """
+    s = sublanes(word_bytes)
+    if radius > s:
+        raise ValueError(f"radius {radius} exceeds the {s}-row sublane tile")
+    span = -(-(s - math.gcd(d_w // 2, s) + d_w) // s) * s
+    return MWDWindow(s=s, span=span, wy=span + 2 * s,
+                     z_ws=n_f + d_w + radius,
+                     nxp=-(-nx // LANES) * LANES)
+
+
+def mwd_vmem_bytes(spec: StencilSpec, d_w: int, n_f: int, nx: int,
+                   word_bytes: int = DEFAULT_WORD_BYTES) -> int:
+    """VMEM bytes one MWD launch needs; the kernel's `vmem_limit_bytes`.
+
+    The tile-padded windows of both parity levels and every coefficient
+    stream, plus one f32 (N_F, span, nxp) value per tap and four more for
+    the live operands of an in-tile update, plus `COMPILER_RESERVE`.
+    """
+    w = mwd_window(spec.radius, d_w, n_f, nx, word_bytes)
+    windows = (2 + spec.n_coeff_arrays) * w.z_ws * w.wy * w.nxp * word_bytes
+    values = (len(spec.taps) + 4) * n_f * w.span * w.nxp * 4
+    return windows + values + COMPILER_RESERVE
+
+
+def vmem_fits(spec: StencilSpec, d_w: int, n_f: int, nx: int,
+              chip: devspecs.DeviceSpec | None = None,
+              word_bytes: int = DEFAULT_WORD_BYTES) -> bool:
+    """VMEM-fit constraint for the auto-tuner: `mwd_vmem_bytes` vs the chip.
+
+    `nx` is the x extent one device holds.  The same byte count is the
+    kernel's `vmem_limit_bytes`, so a plan that passes also compiles.
     """
     chip = chip or devspecs.current_spec()
-    need = cache_block_bytes(spec, d_w, n_f, n_xb)
-    if double_buffer:
-        need += 2.0 * n_xb * n_f * spec.bytes_per_cell  # in+out slab buffers
-    return need <= chip.vmem_bytes
+    return mwd_vmem_bytes(spec, d_w, n_f, nx, word_bytes) <= chip.vmem_bytes
 
 
 # ---------------------------------------------------------------------------
@@ -126,20 +181,20 @@ def mwd_tile_bytes(spec: StencilSpec, d_w: int, n_f: int, nz: int, nx: int,
     """Exact DMA bytes ONE tile moves over its full wavefront sweep.
 
     Window streams in (both parity buffers + coefficient streams, one
-    (N_F, D_w+2R, nx+2R) slab per wavefront step) plus strip emissions out
-    (both parities, (N_F, D_w) per step once the pipeline fills). This is
-    the single source of truth for the kernel's per-tile traffic; the
-    repro.core.traffic counters and the auto-tuner overhead term below both
-    multiply it by their tile counts.
+    (N_F, wy, nxp) slab per wavefront step) plus span emissions out (both
+    parities, (N_F, span, nxp) per step once the pipeline fills), with the
+    tile-aligned extents of `mwd_window`. This is the single source of
+    truth for the kernel's per-tile traffic; the repro.core.traffic
+    counters and the auto-tuner overhead term below both multiply it by
+    their tile counts.
     """
     r = spec.radius
+    w = mwd_window(r, d_w, n_f, nx, word_bytes)
     n_j = -(-(r + nz + d_w) // n_f)          # wavefront steps along z
-    nxp = nx + 2 * r
-    wy = d_w + 2 * r
     n_streams_in = 2 + spec.n_coeff_arrays   # both parities + coeff streams
-    per_step_in = n_streams_in * n_f * wy * nxp * word_bytes
+    per_step_in = n_streams_in * n_f * w.wy * w.nxp * word_bytes
     out_steps = max(0, n_j - d_w // n_f)
-    per_step_out = 2 * n_f * d_w * nxp * word_bytes
+    per_step_out = 2 * n_f * w.span * w.nxp * word_bytes
     return float(n_j * per_step_in + out_steps * per_step_out)
 
 

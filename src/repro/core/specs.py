@@ -13,8 +13,10 @@ machine model as a per-machine input for exactly this reason).
 Resolution (`get_spec`) accepts a committed spec name ("cpu-host"), a path
 to a user spec file, or None for the process default. The default is
 ``$REPRO_DEVICE_SPEC`` when set, else the ``--spec`` flag of the launch
-CLIs (`set_default_spec`), else "tpu-v5e" — the paper target every
-committed model column was produced under.
+CLIs (`set_default_spec`), else the spec of the attached device: on a TPU
+backend the ``device_kind`` is looked up in ``specs/device_kinds.json``
+(a kind missing there is an error), and on any other backend "tpu-v5e" —
+the paper target every committed model column was produced under.
 
 The derived ``latency_bytes = hbm_bw * hbm_latency_cycles / freq`` field is
 the memory-latency crossover: a launch moving fewer HBM bytes than this
@@ -194,17 +196,43 @@ _SPECS: dict[tuple[str, int], DeviceSpec] = {}
 _default_override: str | None = None
 
 
+DEVICE_KINDS_FILE = "device_kinds.json"   # TPU device_kind -> spec name
+
+
+def spec_name_for_kind(kind: str) -> str:
+    """Committed spec name of a TPU `device_kind` (``specs/device_kinds.json``)."""
+    for d in spec_dirs():
+        path = os.path.join(d, DEVICE_KINDS_FILE)
+        if os.path.exists(path):
+            with open(path) as f:
+                table = json.load(f)
+            if kind not in table:
+                raise SpecError(f"TPU device kind {kind!r} has no spec in "
+                                f"{path} (known: {sorted(table)})")
+            return table[kind]
+    raise SpecError(f"no {DEVICE_KINDS_FILE} in {spec_dirs()}")
+
+
+def _device_spec_name() -> str:
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return DEFAULT_SPEC_NAME
+    return spec_name_for_kind(jax.devices()[0].device_kind)
+
+
 def get_spec(name_or_path: str | None = None) -> DeviceSpec:
     """Resolve a device spec by committed name, file path, or default.
 
     `None` resolves the process default: ``$REPRO_DEVICE_SPEC``, then the
-    ``--spec`` CLI override (`set_default_spec`), then "tpu-v5e". Parsed
-    specs are memoized per (path, mtime), so repeated model calls never
-    re-read the file while an edit is still picked up.
+    ``--spec`` CLI override (`set_default_spec`), then the attached
+    device's spec (see the module docstring). Parsed specs are memoized
+    per (path, mtime), so repeated model calls never re-read the file
+    while an edit is still picked up.
     """
     if name_or_path is None:
         name_or_path = (os.environ.get(ENV_SPEC) or _default_override
-                        or DEFAULT_SPEC_NAME)
+                        or _device_spec_name())
     path = _resolve_path(name_or_path)
     try:
         mtime = os.stat(path).st_mtime_ns
@@ -312,7 +340,8 @@ def main(argv=None) -> int:
     files = args.files
     if not files:
         for d in spec_dirs():
-            files = sorted(_glob.glob(os.path.join(d, "*.json")))
+            files = sorted(f for f in _glob.glob(os.path.join(d, "*.json"))
+                           if os.path.basename(f) != DEVICE_KINDS_FILE)
             if files:
                 break
     if not files:

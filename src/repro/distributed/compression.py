@@ -19,8 +19,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def compressed_pmean(g, err, axis_name: str):
     """int8 error-feedback psum-mean along `axis_name` (inside shard_map).
@@ -33,7 +31,7 @@ def compressed_pmean(g, err, axis_name: str):
         amax = jax.lax.pmax(jnp.max(jnp.abs(g_fb)), axis_name)
         scale = jnp.maximum(amax / 127.0, 1e-30)
         q = jnp.clip(jnp.round(g_fb / scale), -127, 127)
-        n = compat.axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         g_mean = jax.lax.psum(q, axis_name) * scale / n
         new_err = g_fb - q * scale
         return g_mean.astype(g.dtype), new_err.astype(err.dtype)

@@ -24,8 +24,6 @@ from typing import Callable
 
 import jax
 
-from repro import compat
-
 
 def plan_mesh(n_devices: int) -> tuple[tuple[int, ...], tuple[str, ...]]:
     """Largest supported mesh for the healthy device count."""
@@ -56,7 +54,9 @@ def build_mesh(n_devices: int | None = None,
             f"requested a {n}-device mesh but only {len(pool)} devices "
             "are healthy")
     shape, axes = plan_mesh(n)
-    return compat.make_mesh(shape, axes, devices=pool[:n])
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=pool[:n])
 
 
 @dataclasses.dataclass

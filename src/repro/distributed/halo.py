@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size as _axis_size
 from repro.distributed import compression
 
 
@@ -43,7 +42,7 @@ def exchange_axis_parts(block, axis_name: str, axis: int, depth: int):
             f"halo depth {depth} exceeds local block extent "
             f"{block.shape[axis]} on axis {axis}: lower t_block or use a "
             f"coarser decomposition (single-hop exchange only)")
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return (_edge_clamp(block, depth, axis, lo=True),
                 _edge_clamp(block, depth, axis, lo=False))
@@ -109,7 +108,7 @@ def exchange_axis_compressed(block, axis_name: str, axis: int, depth: int,
             f"halo depth {depth} exceeds local block extent "
             f"{block.shape[axis]} on axis {axis}: lower t_block or use a "
             f"coarser decomposition (single-hop exchange only)")
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     i = jax.lax.axis_index(axis_name)
     ndim = block.ndim
     lo_idx = [slice(None)] * ndim

@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core import ir
 from repro.core import stencils as st
 from repro.core.mwd import MWDPlan
@@ -823,7 +822,7 @@ def make_super_step(spec: st.StencilSpec, mesh: jax.sharding.Mesh,
         else:
             local = partial(_local_super_step_mwd, spec, plan, t_block, gs,
                             grid_shape, hoisted, pad_g, scalars)
-        kwargs["check_rep"] = False     # no replication rule for pallas_call
+        kwargs["check_vma"] = False     # no replication rule for pallas_call
     elif hoisted and overlap_feasible(spec, mesh, grid_shape, t_block):
         # both schedules share the zone pipeline so every zone computation
         # compiles at the same shape — bitwise equality between them then
@@ -836,7 +835,7 @@ def make_super_step(spec: st.StencilSpec, mesh: jax.sharding.Mesh,
     if compress:
         # one gs.spec() per err subtree: PartitionSpecs act as pytree
         # prefixes, and every residual face shards exactly like the grid
-        fn = _shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(gs.spec(), gs.spec(), _coeff_specs(spec, gs),
@@ -845,7 +844,7 @@ def make_super_step(spec: st.StencilSpec, mesh: jax.sharding.Mesh,
             **kwargs,
         )
     else:
-        fn = _shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(gs.spec(), gs.spec(), _coeff_specs(spec, gs)),
@@ -890,7 +889,7 @@ def make_coeff_extender(spec: st.StencilSpec, mesh: jax.sharding.Mesh,
                         t_block: int):
     """One-time coefficient halo exchange; output feeds hoisted super-steps."""
     gs = GridSharding(mesh)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(_extend_coeffs, spec, t_block, gs),
         mesh=mesh,
         in_specs=(_coeff_specs(spec, gs),),

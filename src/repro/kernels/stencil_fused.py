@@ -122,7 +122,7 @@ def fused_pass(spec: st.StencilSpec, state, arrays, scalars, t_block: int, *,
         out_shape=(out_sds, out_sds),
         scratch_shapes=[pltpu.VMEM(s, cur.dtype) for s in win_shapes]
         + [pltpu.VMEM(win, cur.dtype), pltpu.SemaphoreType.DMA],
-        interpret=config.INTERPRET,
+        interpret=config.interpret(),
     )(*inputs)
 
     # splice: out (z,y) index == original index; x carries the g-pad offset

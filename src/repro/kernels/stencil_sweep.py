@@ -91,7 +91,7 @@ def sweep_step(spec: st.StencilSpec, state, arrays, scalars, *, bz: int = 8):
         out_shape=jax.ShapeDtypeStruct((nzp, nyp, nxp), cur.dtype),
         scratch_shapes=[pltpu.VMEM(s, cur.dtype) for s in win_shapes]
         + [pltpu.SemaphoreType.DMA],
-        interpret=config.INTERPRET,
+        interpret=config.interpret(),
     )(*inputs)
     # splice the computed interior back into the Dirichlet frame:
     # out index == original z index; y/x are padded-coordinate (+r) offsets

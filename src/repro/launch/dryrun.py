@@ -21,8 +21,7 @@ import jax           # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
-from repro import configs  # noqa: E402
+from repro import compile_cache, configs  # noqa: E402
 from repro.configs.base import SHAPES, shape_applicable  # noqa: E402
 from repro.core import stencils as stc  # noqa: E402
 from repro.launch import roofline  # noqa: E402
@@ -70,7 +69,7 @@ def lower_lm_cell(cfg, shape_name: str, mesh, *, chunk: int = 2048,
     params_sh = shd.param_shardings(mesh, spec_tree)
     notes = f"N={n_total/1e9:.2f}B active={n_active/1e9:.2f}B accum={accum}"
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if sinfo["kind"] == "train":
             state_sds, state_sh_fn = steps.train_state_specs(cfg,
                                                              stacked=stacked)
@@ -177,7 +176,7 @@ def lower_girih_cell(arch: str, grid_name: str, mesh, *, t_block: int = 0,
         coeff_sds = stepper.coeff_sds(spec, (nz, ny, nx), dt)
     coeff_sh = (gs.sharding(leading=1), NamedSharding(mesh, P()))
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = stepper.make_super_step(spec, mesh, (nz, ny, nx), tb,
                                        hoisted=hoisted)
         lowered = jax.jit(
@@ -320,6 +319,7 @@ def iter_cells(arch_sel: str, shape_sel: str):
 
 def main():
     """CLI entry point: run the selected cells, appending to --out."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="all",
                     help="arch id, girih-<stencil> (paper, registered custom "

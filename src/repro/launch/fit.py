@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import ir
 from repro.core import specs as devspecs
 from repro.kernels import ops
@@ -121,6 +122,7 @@ def run_fit(op: ir.StencilOp, grid, *, max_steps: int = 200,
 
 def main(argv=None) -> dict:
     """CLI entry point; returns the fit report (tested directly)."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         prog="python -m repro.launch.fit",
         description="Fit per-cell stencil coefficients through the "

@@ -9,14 +9,13 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from repro import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 = 256 chips per pod ('data','model'); two pods add a 'pod' axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def process_grid(devices) -> list[list]:
@@ -69,7 +68,8 @@ def make_process_mesh(devices=None) -> jax.sharding.Mesh:
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
     """Small mesh over however many (possibly forced-host) devices exist."""
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat, configs
+from repro import compile_cache, configs
 from repro.distributed import elastic
 from repro.launch import telemetry as tlm
 from repro.models import lm
@@ -506,6 +506,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None):
     """CLI entry point: stencil request-queue server or LM decode loop."""
+    compile_cache.enable()
     args = build_parser().parse_args(argv)
 
     if args.spec:
@@ -544,7 +545,7 @@ def main(argv=None):
         rng.integers(0, cfg.vocab_size, (args.batch, args.prompt_len)),
         jnp.int32)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.perf_counter()
         _, cache = prefill_into_cache(cfg, params, prompts, args.gen)
         t_prefill = time.perf_counter() - t0

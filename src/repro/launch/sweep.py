@@ -47,6 +47,7 @@ import os
 import tempfile
 import time
 
+from repro import compile_cache
 from repro.core import autotune, ir, models, precision, registry as reg
 from repro.core import specs as devspecs
 from repro.core import stencils as st
@@ -654,6 +655,7 @@ def scaling_points(word_bytes: int = 4, *,
 
 def main(argv=None) -> dict:
     """CLI entry point; returns the sweep summary (tested directly)."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         prog="python -m repro.launch.sweep",
         description="Grid-size sweep: measured GLUP/s + exact B/LUP + "

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat, configs
+from repro import compile_cache, configs
 from repro.data.pipeline import PipelineConfig, SyntheticPipeline
 from repro.distributed import checkpoint, elastic
 from repro.models import lm
@@ -55,6 +55,7 @@ class StepGuard:
 
 def main(argv=None):
     """CLI entry point: build mesh, restore/init state, run the step loop."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b",
                     choices=list(configs.ARCH_IDS))
@@ -104,7 +105,7 @@ def main(argv=None):
     guard = StepGuard()
     jstep = jax.jit(train_step, donate_argnums=(0,))
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for step in range(start_step, args.steps):
             batch = pipe.get_batch(step, cfg)
             t0 = time.perf_counter()

@@ -22,6 +22,7 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro import compile_cache
 from repro.core import autotune, ir, precision, registry as reg
 from repro.core import specs as devspecs
 from repro.core import stencils as st
@@ -92,6 +93,7 @@ def tune_one(spec: st.StencilSpec, grid_shape, registry: reg.PlanRegistry, *,
 
 def main(argv=None) -> list[dict]:
     """CLI entry point; returns the per-stencil reports (tested directly)."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         prog="python -m repro.launch.tune",
         description="Measured MWD auto-tuning with a persistent registry")

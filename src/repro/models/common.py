@@ -9,15 +9,14 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
 
 BATCH = ("pod", "data")
 MODEL = "model"
 
 
 def constrain(x, *axes):
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     names = set(mesh.axis_names)
 

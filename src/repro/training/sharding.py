@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 
 from repro.models.params import ParamSpec, is_spec
 
@@ -65,8 +64,8 @@ def constrain_like_params(tree, spec_tree):
     sharded accumulation (reduce-scatter-like); see docs/REPRODUCTION.md.
     No-op outside a mesh context.
     """
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return tree
     flat, treedef = jax.tree_util.tree_flatten(tree)
     specs = jax.tree_util.tree_leaves(spec_tree, is_leaf=is_spec)

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import ir
 from repro.core import registry as reg
@@ -155,7 +154,7 @@ def test_gradcheck_property(name, data):
 def test_gradcheck_finite_differences(name):
     op = _ALL[name]
     grid, n_steps, eps = _grid_for(op), 2, 1e-5
-    with enable_x64():
+    with jax.enable_x64(True):
         state, arrays, scalars = _problem(op, grid, seed=5,
                                           dtype=jnp.float64)
         rng = np.random.default_rng(11)
@@ -233,7 +232,7 @@ def test_batched_shared_coeffs_forward_matches_mwd_batched():
 def test_distributed_vjp_matches_oracle(name):
     op = st.SPECS[name]
     grid, n_steps = _grid_for(op), 2
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
                          devices=jax.devices()[:1])
     state, arrays, scalars = _problem(op, grid, seed=7)
     coeffs = ir.join_coeffs(op, arrays, scalars)

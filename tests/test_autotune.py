@@ -9,8 +9,8 @@ from repro.core import autotune, models, stencils as st
 def test_result_is_feasible():
     for name, spec in st.SPECS.items():
         res = autotune.autotune(spec, (256, 256, 256), devices_x=1)
-        n_xb = 256 * 4 * spec.bytes_per_cell // res.plan.tg_x
-        assert models.vmem_fits(spec, res.plan.d_w, res.plan.n_f, n_xb)
+        assert models.vmem_fits(spec, res.plan.d_w, res.plan.n_f,
+                                256 // res.plan.tg_x)
         assert res.score > 0
 
 
@@ -39,10 +39,9 @@ def test_light_stencil_prefers_private_tiles():
 
 def test_seed_dw_respects_vmem(monkeypatch):
     spec = st.SPECS["25pt-var"]
-    n_xb = 2048 * 4 * spec.bytes_per_cell
-    d = autotune._seed_d_w(spec, n_xb, hw.V5E)
-    assert models.vmem_fits(spec, d, 1, n_xb)
-    assert not models.vmem_fits(spec, d + 2 * spec.radius, 1, n_xb)
+    d = autotune._seed_d_w(spec, 2048, hw.V5E)
+    assert models.vmem_fits(spec, d, 1, 2048)
+    assert not models.vmem_fits(spec, d + 2 * spec.radius, 1, 2048)
 
 
 def test_fused_execution_preferred():

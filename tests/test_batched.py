@@ -8,6 +8,7 @@ batching, percentiles), the distributed auto-plan per-shard resolution
 helpers, and the serve-loop cache-sizing / --reduced bugfixes.
 """
 
+import jax
 import json
 import math
 
@@ -232,10 +233,10 @@ def test_tune_cli_batched_entry(tmp_path, monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_local_extended_shape_and_cap():
-    from repro import compat
     from repro.distributed import stepper
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     assert stepper.local_extended_shape(SPEC, mesh, (8, 12, 10),
                                         t_block=2) == (12, 16, 14)
     capped = stepper.cap_plan_d_w(SPEC, MWDPlan(d_w=64, n_f=4), 14)
@@ -250,11 +251,11 @@ def test_local_extended_shape_and_cap():
 
 
 def test_run_distributed_rejects_oversized_explicit_plan():
-    from repro import compat
     from repro.core import stencils
     from repro.distributed import stepper
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     state, coeffs = stencils.make_problem(SPEC, (8, 12, 10), seed=0)
     with pytest.raises(ValueError, match="exceeds the per-shard"):
         stepper.run_distributed(SPEC, mesh, state, coeffs, 4, t_block=2,

@@ -22,11 +22,15 @@ from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.core import stencils as st
 from repro.core.mwd import MWDPlan
-from repro.compat import shard_map
+
 from repro.distributed import stepper, compression, checkpoint
 from repro.distributed.stepper import GridSharding
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+def auto(n):
+    return (jax.sharding.AxisType.Auto,) * n
+
+
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), axis_types=auto(3))
 
 # 1. distributed deep-halo stepper == naive, all four stencils
 for name in st.SPECS:
@@ -131,7 +135,7 @@ print("hoisted OK")
 # 2. int8 error-feedback compressed pmean: exact for equal grads,
 #    residual-bounded otherwise, converges under accumulation
 def pod_mean(g, err):
-    f = shard_map(lambda g, e: compression.compressed_pmean(g, e, "pod"),
+    f = jax.shard_map(lambda g, e: compression.compressed_pmean(g, e, "pod"),
                   mesh=mesh, in_specs=(P("pod"), P("pod")),
                   out_specs=(P("pod"), P("pod")))
     return f(g, err)
@@ -190,7 +194,7 @@ state, coeffs = st.make_problem(spec, (8, 8, 16), seed=1)
 out = stepper.run_distributed(spec, mesh, state, coeffs, 2, t_block=2)
 d = sys.argv[2]
 checkpoint.save(d, 2, {"cur": out[0], "prev": out[1]})
-small = jax.make_mesh((2, 2), ("data", "model"),
+small = jax.make_mesh((2, 2), ("data", "model"), axis_types=auto(2),
                       devices=jax.devices()[:4])
 gs = GridSharding(small)
 _, restored = checkpoint.restore(d, {"cur": out[0], "prev": out[1]},
@@ -213,14 +217,19 @@ import sys
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
 import numpy as np
+
+
+def auto(n):
+    return (jax.sharding.AxisType.Auto,) * n
+
 from repro.core import stencils as st
 from repro.core.mwd import MWDPlan
 from repro.distributed import elastic, stepper
 
 MESHES = {
-    1: jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1]),
-    2: jax.make_mesh((2, 1), ("data", "model"), devices=jax.devices()[:2]),
-    8: jax.make_mesh((2, 2, 2), ("pod", "data", "model")),
+    1: jax.make_mesh((1, 1), ("data", "model"), axis_types=auto(2), devices=jax.devices()[:1]),
+    2: jax.make_mesh((2, 1), ("data", "model"), axis_types=auto(2), devices=jax.devices()[:2]),
+    8: jax.make_mesh((2, 2, 2), ("pod", "data", "model"), axis_types=auto(3)),
 }
 
 def check(spec, grid, T, tb, mesh, tol=None, **kw):
@@ -254,7 +263,7 @@ print("overlap bitwise OK")
 #     geometry and the mirrored interior-input chain differ per sharding
 #     case, so bitwise equality is checked there too
 for nd in (2, 8):
-    ymesh = jax.make_mesh((1, nd), ("data", "model"),
+    ymesh = jax.make_mesh((1, nd), ("data", "model"), axis_types=auto(2),
                           devices=jax.devices()[:nd])
     check(st.SPECS["7pt-const"], (24, 64, 8), 4, 2, ymesh)
     check(st.SPECS["25pt-const"], (72, 144, 16), 4, 2, ymesh)

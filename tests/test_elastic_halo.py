@@ -31,7 +31,7 @@ def test_health_monitor():
 def test_halo_single_device_edge_clamp():
     """n==1 path: halos are edge clamps; stepper must equal naive."""
     import jax
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     from repro.distributed import stepper
     spec = st.SPECS["7pt-const"]
     state, coeffs = st.make_problem(spec, (8, 8, 16), seed=0)

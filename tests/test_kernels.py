@@ -81,10 +81,21 @@ def test_mwd_kernel_nonmultiple_grid():
     assert _err(want[0], got[0]) < 5e-4
 
 
+# Kernel vs oracle: the kernel evaluates the oracle's expression cell for
+# cell, in the same order, but reads its operands from aligned VMEM windows.
+# XLA:CPU contracts a different subset of the variable-coefficient
+# multiply-adds into FMAs at those shapes, so those results may move by an
+# ulp. The budget: within ULP_BUDGET ulps of the field's largest magnitude.
+# Constant-coefficient ops stay bitwise.
+ULP_BUDGET = 2
+BITWISE_OPS = ("7pt-const", "25pt-const")
+
+
 @pytest.mark.parametrize("name", list(st.SPECS))
 def test_fused_mwd_matches_oracle_bitwise(name):
-    """The single-launch fused schedule == run_mwd oracle BITWISE, both
-    parities, all four corner-case stencils (interpret mode)."""
+    """The single-launch fused schedule == run_mwd oracle, both parities,
+    all four corner-case stencils (interpret mode): bitwise for the
+    constant-coefficient ops, within ULP_BUDGET for the others."""
     import numpy as np
 
     from repro.core import mwd
@@ -96,8 +107,13 @@ def test_fused_mwd_matches_oracle_bitwise(name):
     t_steps = 5
     want = mwd.run_mwd(spec, state, coeffs, t_steps, mwd.MWDPlan(d_w=d_w))
     got = ops.mwd(spec, state, coeffs, t_steps, d_w=d_w, n_f=n_f, fused=True)
-    np.testing.assert_array_equal(np.asarray(want[0]), np.asarray(got[0]))
-    np.testing.assert_array_equal(np.asarray(want[1]), np.asarray(got[1]))
+    for w, g in zip(want, got):
+        w, g = np.asarray(w), np.asarray(g)
+        if name in BITWISE_OPS:
+            np.testing.assert_array_equal(w, g)
+        else:
+            budget = ULP_BUDGET * np.spacing(np.abs(w).max())
+            assert np.abs(w - g).max() <= budget
 
 
 @pytest.mark.parametrize("name", list(st.SPECS))

@@ -40,10 +40,9 @@ def test_code_balance_monotone_and_below_spatial():
 
 def test_vmem_fit_boundary():
     spec = SPEC_25V
-    n_xb = 1024 * 4 * spec.bytes_per_cell
-    fits_small = models.vmem_fits(spec, 8, 1, n_xb)
+    fits_small = models.vmem_fits(spec, 8, 1, 1024)
     assert fits_small
-    assert not models.vmem_fits(spec, 512, 1, n_xb)
+    assert not models.vmem_fits(spec, 512, 1, 1024)
 
 
 def test_ghostzone_redundancy_bounds():

@@ -5,6 +5,7 @@ $REPRO_PLAN_REGISTRY at its own tmp file, so the process-wide default
 registry cache never leaks state across tests.
 """
 
+import jax
 import dataclasses
 import json
 import math
@@ -237,7 +238,6 @@ def test_run_distributed_accepts_auto_plan(tmp_path, monkeypatch):
     keyed on the PER-SHARD extended block shape the kernel launches on."""
     import numpy as np
 
-    from repro import compat
     from repro.core import stencils
     from repro.distributed import stepper
 
@@ -245,7 +245,8 @@ def test_run_distributed_accepts_auto_plan(tmp_path, monkeypatch):
     monkeypatch.setenv(reg.ENV_VAR, path)
     spec = stencils.SPECS["7pt-const"]
     shape = (8, 12, 10)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shape_e = stepper.local_extended_shape(spec, mesh, shape, t_block=2)
     assert shape_e == (12, 16, 14)      # +2g on every axis, g = R*t_block
     reg.PlanRegistry(path).put(spec, shape_e, MWDPlan(d_w=4, n_f=2), 5.0)

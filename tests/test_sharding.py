@@ -4,24 +4,27 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat, configs
+from repro import configs
 from repro.models import lm
 from repro.models.params import ParamSpec
 from repro.training import sharding as shd, steps
 
 
 def _mesh(shape=(2, 2), axes=("data", "model")):
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * 1)
 
 
 def test_spec_pspec_basic():
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     s = ParamSpec((64, 128), ("embed", "mlp"))
     assert shd.spec_pspec(mesh, s) == P("data", "model")
 
 
 def test_spec_pspec_divisibility_fallback():
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     # 7 not divisible by even a size-1 axis is fine; use a fake big axis via
     # abstract mesh: use mesh of size 1 => divisible; emulate with size check
     s = ParamSpec((7, 128), ("heads", None))
@@ -30,14 +33,16 @@ def test_spec_pspec_divisibility_fallback():
 
 
 def test_spec_pspec_dedup_expert_wins():
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     s = ParamSpec((8, 64, 128), ("experts", "embed", "mlp"))
     p = shd.spec_pspec(mesh, s)
     assert p == P("model", "data", None)  # mlp loses 'model' to experts
 
 
 def test_param_shardings_cover_tree():
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",),
+        axis_types=(jax.sharding.AxisType.Auto,) * 1)
     cfg = configs.reduced(configs.get("mixtral-8x7b"))
     tree = lm.param_specs(cfg)
     sh = shd.param_shardings(mesh, tree)
@@ -66,7 +71,8 @@ def test_input_specs_all_cells_enumerate():
 
 
 def test_cache_shardings_rightmost_anchored():
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = configs.reduced(configs.get("gemma3-1b"))
     for stacked in (False, True):
         tree = lm.cache_spec(cfg, 4, 64, stacked=stacked)

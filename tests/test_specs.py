@@ -70,6 +70,43 @@ def test_unknown_spec_name_raises():
         devspecs.get_spec("no-such-machine")
 
 
+def test_tpu_device_kind_resolves_through_table(monkeypatch):
+    """On a TPU the default spec follows device_kind; unknown kinds fail."""
+    assert devspecs.spec_name_for_kind("TPU v5 lite") == "tpu-v5e"
+    with pytest.raises(devspecs.SpecError, match="TPU v9 imaginary"):
+        devspecs.spec_name_for_kind("TPU v9 imaginary")
+    import jax
+
+    class Dev:
+        device_kind = "TPU v9 imaginary"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda: [Dev()])
+    with pytest.raises(devspecs.SpecError, match="no spec"):
+        devspecs.current_spec()
+    Dev.device_kind = "TPU v5 lite"
+    assert devspecs.current_spec().name == "tpu-v5e"
+
+
+def test_compile_cache_honours_env_else_checkout_dir(monkeypatch):
+    import jax
+
+    from repro import compile_cache
+
+    old = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.ENV_VAR, "/elsewhere/cache")
+    assert compile_cache.enable() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == old     # left to JAX
+    monkeypatch.delenv(compile_cache.ENV_VAR)
+    try:
+        path = compile_cache.enable()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".repro_cache", "jax")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+
+
 # ---------------------------------------------------------------------------
 # Schema validation
 # ---------------------------------------------------------------------------
@@ -122,6 +159,9 @@ def test_cli_validates_and_rejects(tmp_path, capsys):
     assert devspecs.main([str(bad)]) == 1
     out = capsys.readouterr().out
     assert "ok " in out and "FAIL" in out
+    # no arguments: every committed spec, not the device-kind table
+    assert devspecs.main([]) == 0
+    assert devspecs.DEVICE_KINDS_FILE not in capsys.readouterr().out
 
 
 # ---------------------------------------------------------------------------
