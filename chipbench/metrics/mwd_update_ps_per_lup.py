@@ -1,0 +1,14 @@
+"""mwd_update_ps_per_lup: the MWD kernel's in-tile update time, in ps per LUP.
+
+Device time of the kernel's ``mwd.update`` regions (the T masked updates of
+a grid step with their iota and mask set-up), summed over the cell's chips,
+over the LUPs of the traced calls. None when the trace holds no complete
+regions (`chipbench.regions`).
+"""
+
+from chipbench import regions
+
+
+def read(run):
+    """Update region time per LUP, or None."""
+    return regions.region_ps_per_lup(run, ("mwd.update",))

@@ -19,6 +19,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import ir, precision
 from repro.core.mwd import MWDPlan
@@ -97,6 +98,7 @@ def _mwd(spec, state, arrays, scalars, n_steps, d_w, n_f, fused, acc=None):
                                d_w=d_w, n_f=n_f, fused=fused, acc_dtype=acc)
 
 
+@partial(jax.profiler.annotate_function, name="repro.mwd")
 def mwd(spec: StencilSpec, state, coeffs, n_steps: int,
         d_w: int = 8, n_f: int = 2, fused: bool = True,
         plan: MWDPlan | str | None = None, dtype=None, acc="auto"):
@@ -120,19 +122,25 @@ def mwd(spec: StencilSpec, state, coeffs, n_steps: int,
     acc: accumulator policy for the in-tile updates — "auto" (f32
     accumulation for sub-32-bit streams), "native", or an explicit dtype
     (`core.precision.resolve_acc`).
+
+    Profiler host spans: ``repro.mwd`` (the whole call), inside it
+    ``repro.mwd.plan`` (plan resolution, when `plan` is given) and
+    ``repro.mwd.launch`` (dispatch of the jitted program).
     """
     if dtype is not None:
         dt = precision.parse_dtype(dtype)
         state = tuple(jnp.asarray(s, dt) for s in state)
     if plan is not None:
-        p = resolve_plan(spec, state, plan)
+        with TraceAnnotation("repro.mwd.plan"):
+            p = resolve_plan(spec, state, plan)
         d_w, n_f, fused = p.d_w, p.n_f, p.fused
     arrays, scalars = _split_coeffs(spec, coeffs)
     if dtype is not None and arrays is not None:
         arrays = jnp.asarray(arrays, dt)
     acc_dt = precision.resolve_acc(state[0].dtype, acc)
-    return _mwd(spec, state, arrays, scalars, n_steps, d_w, n_f, fused,
-                acc_dt)
+    with TraceAnnotation("repro.mwd.launch"):
+        return _mwd(spec, state, arrays, scalars, n_steps, d_w, n_f, fused,
+                    acc_dt)
 
 
 @partial(jax.jit, static_argnames=("spec", "scalars", "n_steps", "d_w", "n_f",

@@ -60,13 +60,15 @@ def sync_dirichlet_frame(cur, prev, r: int):
 
     Operates on the trailing (z, y, x) axes, so a leading batch axis — the
     batched serving path stacks B independent grids — passes through.
+    The copies run under the scope ``mwd.frame_sync``.
     """
-    for ax in range(3):
-        lo = (...,) + tuple(slice(None) if a != ax else slice(0, r)
-                            for a in range(3))
-        hi = (...,) + tuple(slice(None) if a != ax else slice(-r, None)
-                            for a in range(3))
-        prev = prev.at[lo].set(cur[lo]).at[hi].set(cur[hi])
+    with jax.named_scope("mwd.frame_sync"):
+        for ax in range(3):
+            lo = (...,) + tuple(slice(None) if a != ax else slice(0, r)
+                                for a in range(3))
+            hi = (...,) + tuple(slice(None) if a != ax else slice(-r, None)
+                                for a in range(3))
+            prev = prev.at[lo].set(cur[lo]).at[hi].set(cur[hi])
     return prev
 
 
@@ -111,6 +113,12 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
     write and the emission copies out. The rows of the span the tile does
     not own are copied back unchanged: they hold what HBM held when the
     window loaded them, and no other tile runs in between.
+
+    Each phase of an active grid step runs under a `jax.named_scope`, which
+    Mosaic lowers to a profiler region (``tpu.trace_start``/``trace_stop``):
+    ``mwd.shift``, ``mwd.fetch``, ``mwd.update`` and, on the steps that
+    emit, ``mwd.emit``. A TPU profile shows them (line ``XLA TraceMe``)
+    when libtpu runs with ``--xla_enable_custom_call_region_trace=true``.
     """
     bounds_ref, p0_ref, ys_ref, y0_ref, y1_ref, act_ref = refs[:6]
     inputs = refs[6:6 + n_in]
@@ -129,29 +137,8 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
     ys = pl.multiple_of(ys_ref[tile], s)
     srcs = [out_e, out_o] + list(inputs[2:])
 
-    def tile_step():
-        @pl.when(j == 0)
-        def _init():
-            for b in bufs:
-                b[...] = jnp.zeros_like(b)
-
-        # --- shift the wavefront window down by N_F, stream next slabs in --
-        for b in bufs:
-            if len(b.shape) == 3:
-                b[0:z_ws - n_f] = b[n_f:z_ws]
-            else:
-                b[:, 0:z_ws - n_f] = b[:, n_f:z_ws]
-        for src, dst in zip(srcs, bufs):
-            if len(dst.shape) == 3:       # solution window (scratch is 3-D)
-                idx = bsel + (pl.ds(j * n_f, n_f), pl.ds(ys, wy))
-                didx = (pl.ds(z_ws - n_f, n_f),)
-            else:                         # stacked coefficient window
-                idx = bsel + (slice(None), pl.ds(j * n_f, n_f), pl.ds(ys, wy))
-                didx = (slice(None), pl.ds(z_ws - n_f, n_f))
-            cp = pltpu.make_async_copy(src.at[idx], dst.at[didx], sem)
-            cp.start()
-            cp.wait()
-
+    def update_phase():
+        """The T masked in-tile updates, with their iota and mask set-up."""
         coeff_buf = bufs[2] if spec.n_coeff_arrays else None
         nxp = win.nxp
         shape = (n_f, span, nxp)
@@ -208,17 +195,46 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
             def _upd(p0=p0):
                 updates(p0)
 
+    def tile_step():
+        @pl.when(j == 0)
+        def _init():
+            for b in bufs:
+                b[...] = jnp.zeros_like(b)
+
+        # --- shift the wavefront window down by N_F, stream next slabs in --
+        with jax.named_scope("mwd.shift"):
+            for b in bufs:
+                if len(b.shape) == 3:
+                    b[0:z_ws - n_f] = b[n_f:z_ws]
+                else:
+                    b[:, 0:z_ws - n_f] = b[:, n_f:z_ws]
+        with jax.named_scope("mwd.fetch"):
+            for src, dst in zip(srcs, bufs):
+                if len(dst.shape) == 3:   # solution window (scratch is 3-D)
+                    idx = bsel + (pl.ds(j * n_f, n_f), pl.ds(ys, wy))
+                    didx = (pl.ds(z_ws - n_f, n_f),)
+                else:                     # stacked coefficient window
+                    idx = bsel + (slice(None), pl.ds(j * n_f, n_f),
+                                  pl.ds(ys, wy))
+                    didx = (slice(None), pl.ds(z_ws - n_f, n_f))
+                cp = pltpu.make_async_copy(src.at[idx], dst.at[didx], sem)
+                cp.start()
+                cp.wait()
+        with jax.named_scope("mwd.update"):
+            update_phase()
+
         # --- emit the completed slab (both parities) ----------------------
         @pl.when(j >= d_w // n_f)
         def _out():
-            zs = j * n_f - d_w
-            for out, b in ((out_e, bufs[0]), (out_o, bufs[1])):
-                cp = pltpu.make_async_copy(
-                    b.at[pl.ds(r, n_f), pl.ds(s, span)],
-                    out.at[bsel + (pl.ds(zs, n_f), pl.ds(ys + s, span))],
-                    osem)
-                cp.start()
-                cp.wait()
+            with jax.named_scope("mwd.emit"):
+                zs = j * n_f - d_w
+                for out, b in ((out_e, bufs[0]), (out_o, bufs[1])):
+                    cp = pltpu.make_async_copy(
+                        b.at[pl.ds(r, n_f), pl.ds(s, span)],
+                        out.at[bsel + (pl.ds(zs, n_f), pl.ds(ys + s, span))],
+                        osem)
+                    cp.start()
+                    cp.wait()
 
     if fused:
         # inactive edge tiles own no spans: skip their streams entirely
@@ -330,7 +346,8 @@ def _mwd_run_impl(spec: st.StencilSpec, state, arrays, scalars, n_steps: int,
     pads = ((pz, nz_tot - nz - pz), (py, nyp - ny - py), (0, nxp - nx))
 
     def pad(a):
-        return jnp.pad(a, ((0, 0),) * (a.ndim - 3) + pads, mode="edge")
+        with jax.named_scope("mwd.pad"):
+            return jnp.pad(a, ((0, 0),) * (a.ndim - 3) + pads, mode="edge")
 
     bufs = [pad(cur), pad(prev)]         # parity 0 (even), parity 1 (odd)
     wshape = (win.z_ws, win.wy, nxp)
@@ -392,4 +409,5 @@ def _mwd_run_impl(spec: st.StencilSpec, state, arrays, scalars, n_steps: int,
 
     core = (..., slice(pz, pz + nz), slice(py, py + ny), slice(0, nx))
     p = n_steps % 2
-    return bufs[p][core], bufs[1 - p][core]
+    with jax.named_scope("mwd.crop"):
+        return bufs[p][core], bufs[1 - p][core]

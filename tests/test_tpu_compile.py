@@ -8,6 +8,10 @@ inside fixtures, so only the worker that runs this file loads the TPU
 compiler.
 """
 
+import base64
+import json
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -123,3 +127,48 @@ def test_vmem_prune_refuses_what_mosaic_refuses(one_chip, mosaic):
 
     with pytest.raises(Exception):
         jax.jit(fwd).lower(state, arrays).compile()
+
+
+REGIONS = ("mwd.shift", "mwd.fetch", "mwd.update", "mwd.emit")
+
+
+def _mosaic_texts(lowered):
+    """The Mosaic modules of a lowered program's ``tpu_custom_call``s."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir as mlir_ir
+    from jax.experimental.mosaic.dialects import tpu
+
+    ctx = mlir.JaxIrContext()
+    ctx.append_dialect_registry(mlir.upstream_dialects)
+    ctx.load_all_available_dialects()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True   # the serialized stable_mosaic
+    texts = []
+    for cfg in re.findall(r'backend_config = "(\{.*?\})"', lowered.as_text()):
+        body = json.loads(cfg.replace("\\22", '"'))["custom_call_config"]["body"]
+        with ctx:
+            texts.append(str(mlir_ir.Module.parse(base64.b64decode(body))))
+    return texts
+
+
+@pytest.mark.parametrize("name", ["7pt-var", "25pt-const"])
+def test_mwd_kernel_has_balanced_phase_regions(name, one_chip, mosaic):
+    """Each phase of a grid step is one profiler region of the kernel."""
+    spec = st.SPECS[name]
+    state, arrays, scalars = _operands(spec, FORWARD[name], one_chip)
+
+    def fwd(state, arrays):
+        return ops.mwd(spec, state, ir.join_coeffs(spec, arrays, scalars),
+                       16, plan=PLAN)
+
+    texts = _mosaic_texts(jax.jit(fwd).lower(state, arrays))
+    assert len(texts) == 1
+    ops_ = re.findall(r'tpu\.trace_(start|stop)"\(\)(?: \{[^}]*message = '
+                      r'"([^"]*)"[^}]*\})?', texts[0])
+    starts = [m for kind, m in ops_ if kind == "start"]
+    assert sorted(starts) == sorted(REGIONS)
+    depth = 0
+    for kind, _ in ops_:
+        depth += 1 if kind == "start" else -1
+        assert depth in (0, 1)        # regions neither nest nor overlap
+    assert depth == 0
