@@ -14,9 +14,12 @@ space-time schedule (the per-row launch mode is kept for comparison):
     (+ coefficient streams) for one extruded diamond tile; every step j
     shifts the window down N_F z-rows ("pipelined" wavefront, Fig. 6c) and
     DMAs the next slab of every stream HBM->VMEM;
-  * T = D_w/R in-tile time-step updates run at static z-offsets, each masked
-    to the diamond's y-range at that local time (diamonds via masking:
-    rectangular VMEM blocks, non-rectangular iteration space — see DESIGN.md);
+  * T = D_w/R in-tile time-step updates run at static z-offsets. Each
+    computes only the sublane tiles that cover the diamond's y-range at
+    that local time, masked to the range, and is skipped where the range
+    is empty or its slab lies outside the interior (diamonds via masking:
+    rectangular VMEM blocks, non-rectangular iteration space — see
+    DESIGN.md; `update_work` counts the rows);
   * one completed slab per parity DMAs back to HBM per step.
 
 In-place safety: tiles of one row touch a same-row neighbor's cells only in
@@ -42,16 +45,19 @@ Geometry (see DESIGN.md): update tau processes padded z-rows
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import ir, models
 from repro.core import stencils as st
 from repro.core import tiling
+from repro.core.precision import DEFAULT_WORD_BYTES
 from repro.kernels import config
 
 
@@ -72,9 +78,122 @@ def sync_dirichlet_frame(cur, prev, r: int):
     return prev
 
 
+@dataclasses.dataclass(frozen=True)
+class LaunchGeometry:
+    """Static geometry of one MWD launch (see `launch_geometry`)."""
+
+    win: models.MWDWindow
+    comp: tiling.CompiledSchedule
+    py: int                  # y padding before the grid's first row
+    pz: int                  # z padding before the grid's first plane
+    nyp: int                 # padded y extent
+    n_j: int                 # wavefront steps per tile
+    ys: np.ndarray           # (n_rows, n_tiles) window starts, padded y
+    level_rows: tuple[int, ...]   # per tau: rows its update computes
+
+
+@functools.lru_cache(maxsize=256)
+def launch_geometry(r: int, d_w: int, n_f: int, shape: tuple[int, int, int],
+                    word: int, n_steps: int,
+                    y_domain: tuple[int, int] | None = None
+                    ) -> LaunchGeometry | None:
+    """The compiled schedule, padding and window placement of one launch.
+
+    `shape` is the (nz, ny, nx) grid; None when n_steps is 0 (no launch).
+
+    level_rows[tau] is the sublane-aligned number of window rows the
+    update of in-tile level tau computes: the widest aligned cover of
+    that level's y-range over every tile of the schedule, and 0 where no
+    tile has rows at that level (the kernel emits no code for it). A
+    tile's level then covers the rows [a, a + level_rows[tau]) of its
+    window, a = its range start floored to the sublane tile and clamped
+    into the span.
+    """
+    nz, ny, nx = shape
+    win = models.mwd_window(r, d_w, n_f, nx, word)
+    s = win.s
+    y_lo, y_hi = y_domain if y_domain is not None else (r, ny - r)
+    comp = tiling.compile_schedule(
+        tiling.make_diamond_schedule(d_w, r, n_steps, y_lo, y_hi))
+    if comp.n_rows == 0:
+        return None
+
+    # y padding: every window starts at a non-negative multiple of s, and
+    # (y_lo + py) % s == 0 keeps each tile's owned rows within its span
+    own = comp.w0 + r                    # first owned row, domain coords
+    py = s - int(own.min())
+    py += (-(y_lo + py)) % s
+    ys = (own + py) // s * s - s         # aligned window starts
+    assert (own + py - ys - s + d_w).max() <= win.span, "span misses rows"
+    nyp = -(-max(int(ys.max()) + win.wy, py + ny) // s) * s
+    pz = r
+    n_j = -(-(pz + nz + d_w) // n_f)
+
+    # aligned cover of each (tile, tau) range, in window rows
+    y0 = comp.y0 + py - ys[..., None]
+    y1 = comp.y1 + py - ys[..., None]
+    cover = np.where(comp.y1 > comp.y0, -(-y1 // s) * s - y0 // s * s, 0)
+    level_rows = tuple(int(c) for c in cover.max(axis=(0, 1)))
+    assert max(level_rows) <= win.span, "a level leaves the span"
+    ys.setflags(write=False)
+    return LaunchGeometry(win=win, comp=comp, py=py, pz=pz, nyp=nyp,
+                          n_j=n_j, ys=ys, level_rows=level_rows)
+
+
+@dataclasses.dataclass(frozen=True)
+class UpdateWork:
+    """Window rows the in-tile updates cover, per (row, tile, tau).
+
+    Counted over the fused launch's active tiles, per wavefront step
+    (every step of a tile runs the same updates): `computed` is what the
+    kernel computes (level_rows[tau] where the tile's level is not empty),
+    `full_span` what an update over the whole span at every level computed,
+    `useful` the rows of the diamond's range. The kernel also skips the
+    levels whose slab lies outside the interior, at the wavefront's head
+    and tail; these counts leave that out.
+    """
+
+    computed: np.ndarray
+    full_span: np.ndarray
+    useful: np.ndarray
+
+    def shares(self) -> dict[str, float]:
+        """Non-empty levels and computed and useful rows, over full_span."""
+        full = float(self.full_span.sum())
+        return {"non_empty": float((self.useful > 0).sum()
+                                   / (self.full_span > 0).sum()),
+                "computed": float(self.computed.sum()) / full,
+                "useful": float(self.useful.sum()) / full}
+
+
+@functools.lru_cache(maxsize=64)
+def update_work(spec: st.StencilSpec, shape: tuple[int, int, int],
+                n_steps: int, d_w: int, n_f: int, *,
+                y_domain: tuple[int, int] | None = None,
+                word_bytes: int = DEFAULT_WORD_BYTES) -> UpdateWork | None:
+    """The in-tile update work of `mwd_run` on a (nz, ny, nx) grid.
+
+    Read from the same tables the launch runs (`launch_geometry`); None
+    when n_steps is 0. Memoized.
+    """
+    geo = launch_geometry(spec.radius, d_w, n_f, shape, word_bytes, n_steps,
+                          y_domain)
+    if geo is None:
+        return None
+    comp = geo.comp
+    act = comp.active.astype(bool)[..., None]
+    useful = np.where(act, comp.y1 - comp.y0, 0)
+    computed = np.where(useful > 0, np.asarray(geo.level_rows), 0)
+    full_span = np.where(np.broadcast_to(act, useful.shape), geo.win.span, 0)
+    for a in (computed, full_span, useful):
+        a.setflags(write=False)
+    return UpdateWork(computed=computed, full_span=full_span, useful=useful)
+
+
 def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
                 n_in: int, fused: bool, batched: bool, acc_dtype,
-                win: models.MWDWindow, n_tiles: int, *refs):
+                win: models.MWDWindow, n_tiles: int,
+                level_rows: tuple[int, ...], *refs):
     """One (row, tile, j) grid step of the MWD schedule.
 
     refs = (bounds, p0s, ys, y0s, y1s, active,      # scalar prefetch, flat
@@ -138,62 +257,110 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
     srcs = [out_e, out_o] + list(inputs[2:])
 
     def update_phase():
-        """The T masked in-tile updates, with their iota and mask set-up."""
+        """The in-tile updates of the levels with rows, and their masks.
+
+        Level tau computes the `level_rows[tau]` window rows from the
+        aligned row `a` on, and only when its y-range and its z-slab meet
+        the interior. Rows it does not compute keep `old`, which is what a
+        masked write over the whole span would have stored there, so the
+        result is unchanged.
+        """
         coeff_buf = bufs[2] if spec.n_coeff_arrays else None
         nxp = win.nxp
-        shape = (n_f, span, nxp)
-        y_io = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + (ys + s)
-        x_io = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
-        z_loc = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         # Dirichlet / shard-interior bounds, dynamic (padded coordinates)
         lo_z, hi_z = bounds_ref[0], bounds_ref[1]
         lo_y, hi_y = bounds_ref[2], bounds_ref[3]
         lo_x, hi_x = bounds_ref[4], bounds_ref[5]
-        xy_mask = ((x_io >= lo_x) & (x_io < hi_x)
-                   & (y_io >= lo_y) & (y_io < hi_y))
 
         def cast(v):
             return v if acc_dtype is None else v.astype(acc_dtype)
 
-        # --- T in-tile updates at static buffer offsets -------------------
-        def updates(p0: int):
-            for tau in range(t_steps):
-                zb = r * (t_steps - tau)    # buffer row of the N_F targets
-                p = (p0 + tau) % 2
-                src_b, dst_b = bufs[p], bufs[1 - p]
+        def level(tau: int, rows: int, p: int, a, y_lo, y_hi, z_lo, z_hi):
+            zb = r * (t_steps - tau)        # buffer row of the N_F targets
+            src_b, dst_b = bufs[p], bufs[1 - p]
+            cols = pl.ds(a, rows)
+            halo = {}                       # dz -> rows [a - s, a + rows + s)
 
-                def tap(off, src_b=src_b, zb=zb):
-                    dz, dy, dx = off
-                    v = cast(src_b[zb + dz:zb + dz + n_f,
-                                   s + dy:s + dy + span])
-                    # x is never sliced at an offset: a lane rotation reads
-                    # x + dx, wrapping only within R of the lane edges,
-                    # which hold no interior cell
-                    return pltpu.roll(v, (-dx) % nxp, 2) if dx else v
+            def tap(off):
+                dz, dy, dx = off
+                z = slice(zb + dz, zb + dz + n_f)
+                if dy:
+                    # Mosaic loads dynamic sublane offsets only when
+                    # aligned: load the aligned halo once per dz and shift
+                    # by dy in registers
+                    if dz not in halo:
+                        halo[dz] = src_b[z, pl.ds(a - s, rows + 2 * s)]
+                    v = jax.lax.slice_in_dim(halo[dz], s + dy, s + dy + rows,
+                                             axis=1)
+                else:
+                    v = src_b[z, cols]
+                v = cast(v)
+                # x is never sliced at an offset: a lane rotation reads
+                # x + dx, wrapping only within R of the lane edges, which
+                # hold no interior cell
+                return pltpu.roll(v, (-dx) % nxp, 2) if dx else v
 
-                def coeff(c, zb=zb):
-                    if c.kind == "const":
-                        return scalars[c.index]
-                    return cast(coeff_buf[c.index, zb:zb + n_f, s:s + span])
+            def coeff(c):
+                if c.kind == "const":
+                    return scalars[c.index]
+                return cast(coeff_buf[c.index, zb:zb + n_f, cols])
 
-                old = dst_b[zb:zb + n_f, s:s + span]
-                new = ir.update(spec, tap, coeff, lambda old=old: cast(old))
-                if acc_dtype is not None:
-                    new = new.astype(dst_b.dtype)
+            old = dst_b[zb:zb + n_f, cols]
+            new = ir.update(spec, tap, coeff, lambda: cast(old))
+            if acc_dtype is not None:
+                new = new.astype(dst_b.dtype)
 
-                y0 = y0_ref[tile * t_steps + tau]
-                y1 = y1_ref[tile * t_steps + tau]
-                z_io = z_loc + (j * n_f - (tau + 1) * r)  # padded z coord
-                mask = ((y_io >= y0) & (y_io < y1)
-                        & (z_io >= lo_z) & (z_io < hi_z) & xy_mask)
-                dst_b[zb:zb + n_f, s:s + span] = jnp.where(mask, new, old)
+            def iota(axis):
+                return jax.lax.broadcasted_iota(jnp.int32, old.shape, axis)
+
+            y_i, x_i = iota(1), iota(2)
+            mask = ((y_i >= y_lo) & (y_i < y_hi)
+                    & (x_i >= lo_x) & (x_i < hi_x))
+            if n_f > 1:                     # a one-row slab is live in z
+                z_i = iota(0)
+                mask &= (z_i >= z_lo) & (z_i < z_hi)
+            dst_b[zb:zb + n_f, cols] = jax.lax.select(mask, new, old)
+
+        # --- in-tile updates at static buffer offsets -------------------
+        # Scalar bounds use lax directly: an operator on a traced scalar
+        # traces a jitted function, and these run for every level.
+        lax = jax.lax
+        first, jz = tile * t_steps, j * n_f
+        y_lo0, y_hi0 = lax.sub(lo_y, ys), lax.sub(hi_y, ys)
+        z_lo0 = lax.sub(lo_z, np.int32(n_f))
+        levels = []
+        for tau, rows in enumerate(level_rows):
+            if not rows:                    # no tile has rows at this level
+                continue
+            # the level's y-range in window rows and its slab's padded z;
+            # a level with no rows or a slab outside the interior holds all
+            at = lax.add(first, np.int32(tau))
+            y0 = lax.sub(y0_ref[at], ys)
+            y_lo = lax.max(y0, y_lo0)
+            y_hi = lax.min(lax.sub(y1_ref[at], ys), y_hi0)
+            z_at = lax.sub(jz, np.int32((tau + 1) * r))
+            live = lax.bitwise_and(
+                lax.lt(y_lo, y_hi),
+                lax.bitwise_and(lax.lt(z_at, hi_z), lax.gt(z_at, z_lo0)))
+            # the aligned rows [a, a + rows) of the window hold the range;
+            # the mask is relative to row a and to the slab's first z
+            a = pl.multiple_of(
+                lax.min(lax.mul(lax.div(y0, np.int32(s)), np.int32(s)),
+                        np.int32(s + span - rows)), s)
+            levels.append((tau, rows, live, (
+                a, lax.sub(y_lo, a), lax.sub(y_hi, a),
+                lax.sub(lo_z, z_at), lax.sub(hi_z, z_at))))
 
         # buffer parity of the row's first time level is a prefetched scalar;
         # refs cannot be selected dynamically, so branch on it statically
+        p_row = p0_ref[row]
         for p0 in (0, 1):
-            @pl.when(p0_ref[row] == p0)
+            @pl.when(lax.eq(p_row, np.int32(p0)))
             def _upd(p0=p0):
-                updates(p0)
+                for tau, rows, live, bounds in levels:
+                    @pl.when(live)
+                    def _level(tau=tau, rows=rows, bounds=bounds):
+                        level(tau, rows, (p0 + tau) % 2, *bounds)
 
     def tile_step():
         @pl.when(j == 0)
@@ -321,25 +488,12 @@ def _mwd_run_impl(spec: st.StencilSpec, state, arrays, scalars, n_steps: int,
     nz, ny, nx = cur.shape[-3:]
     lead = cur.shape[:-3]                # (B,) when batched, () otherwise
     word = jnp.dtype(cur.dtype).itemsize
-    win = models.mwd_window(r, d_w, n_f, nx, word)
-    s, nxp = win.s, win.nxp
-
-    y_lo, y_hi = y_domain if y_domain is not None else (r, ny - r)
-    comp = tiling.compile_schedule(
-        tiling.make_diamond_schedule(d_w, r, n_steps, y_lo, y_hi))
-    if comp.n_rows == 0:                 # n_steps == 0: nothing to launch
+    geo = launch_geometry(r, d_w, n_f, (nz, ny, nx), word, n_steps,
+                          None if y_domain is None else tuple(y_domain))
+    if geo is None:                      # n_steps == 0: nothing to launch
         return cur, prev
-
-    # y padding: every window starts at a non-negative multiple of s, and
-    # (y_lo + py) % s == 0 keeps each tile's owned rows within its span
-    own = comp.w0 + r                    # first owned row, domain coords
-    py = s - int(own.min())
-    py += (-(y_lo + py)) % s
-    ys = (own + py) // s * s - s         # aligned window starts
-    assert (own + py - ys - s + d_w).max() <= win.span, "span misses rows"
-    nyp = -(-max(int(ys.max()) + win.wy, py + ny) // s) * s
-    pz = r
-    n_j = -(-(pz + nz + d_w) // n_f)
+    win, comp, py, pz, ys = geo.win, geo.comp, geo.py, geo.pz, geo.ys
+    s, nxp, nyp, n_j = win.s, win.nxp, geo.nyp, geo.n_j
     nz_tot = n_j * n_f
     # x is not offset: lane rotations read the x halo, so the only x
     # padding rounds the lanes up to whole tiles
@@ -378,7 +532,7 @@ def _mwd_run_impl(spec: st.StencilSpec, state, arrays, scalars, n_steps: int,
     def launch(fused_mode, tables, n_rows, bufs_in):
         kern = functools.partial(_mwd_kernel, spec, d_w, n_f, scalars,
                                  n_in, fused_mode, batched, acc_dtype, win,
-                                 comp.n_tiles)
+                                 comp.n_tiles, geo.level_rows)
         # parity grids aliased in place: inputs 6/7 after the six
         # scalar-prefetch tables -> outputs 0/1
         return pl.pallas_call(
