@@ -2,8 +2,12 @@
 
 A cell is one entry of ``workloads``: a configuration (its file is named in
 ``configs``), a traffic mix (``chipbench/traffic/<traffic>.json``), the
-entry adapter the traffic names (``chipbench/entries/<entry>.py``) and the
-metric readers it reports (``chipbench/metrics/<metric>.py``). Everything is
+entry adapter the traffic names (``chipbench/entries/<entry>.py``), the
+metric readers it reports (``chipbench/metrics/<metric>.py``), the plain
+reference step of the configuration's op
+(``chipbench/reference/ops/<op>.py``) and its coefficient draw
+(``chipbench/draws/<kind>.py``). So a new configuration, traffic mix or
+metric joins the benchmark as new files and entries alone. Everything is
 resolved before any device work, so a name that does not resolve fails
 fast, with no metrics line.
 """
@@ -35,12 +39,17 @@ def _load_json(path: str) -> dict:
 
 
 def load_module(kind: str, name: str, root: str = ROOT) -> types.ModuleType:
-    """Import ``<root>/chipbench/<kind>/<name>.py`` (names may hold dots)."""
+    """Import ``<root>/chipbench/<kind>/<name>.py``.
+
+    `kind` is a directory under chipbench/ (``reference/ops``); names may
+    hold dots and dashes.
+    """
     path = os.path.join(root, "chipbench", kind, f"{name}.py")
     if not os.path.isfile(path):
         raise CellError(f"no {kind} file {os.path.relpath(path, root)}")
     spec = importlib.util.spec_from_file_location(
-        f"chipbench.{kind}.{name.replace('.', '_').replace('-', '_')}", path)
+        f"chipbench.{kind.replace('/', '.')}."
+        f"{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -89,6 +98,9 @@ def load_cell(workload: str, bench: dict | None = None,
     if traffic["chips"] != w["chips"]:
         raise CellError(f"traffic {w['traffic']!r} is for {traffic['chips']} "
                         f"chips, workload {workload!r} asks {w['chips']}")
+    load_module("reference/ops", config["op"], root)
+    if config["coefficients"]["arrays"]:
+        load_module("draws", config["coefficients"]["draw"], root)
     return Cell(name=workload, chips=w["chips"], config=config,
                 traffic=traffic,
                 entry=load_module("entries", traffic["entry"], root),

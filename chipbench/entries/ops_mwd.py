@@ -23,6 +23,21 @@ def _plan_arg(traffic: dict):
     return MWDPlan(d_w=plan["d_w"], n_f=plan["n_f"])
 
 
+def _program(config: dict, traffic: dict):
+    """The timed call as a function of (state, coefficient arrays)."""
+    from repro.core import ir
+    from repro.kernels import ops
+
+    spec = ir.OPS[config["op"]]
+    scalars = tuple(config["coefficients"]["scalars"])
+    plan = _plan_arg(traffic)
+
+    def call(state, arrays):
+        return ops.mwd(spec, state, ir.join_coeffs(spec, arrays, scalars),
+                       traffic["steps_per_call"], plan=plan)
+    return call
+
+
 def shardings(config: dict, traffic: dict, devices):
     """Where the draw places state and coefficients: the first device."""
     del config, traffic
@@ -36,6 +51,7 @@ class Entry:
     def __init__(self, config: dict, traffic: dict, devices, arrays):
         from repro.core import ir, registry
 
+        self.config, self.traffic, self.arrays = config, traffic, arrays
         self.spec = ir.OPS[config["op"]]
         self.n_steps = traffic["steps_per_call"]
         self.grid = tuple(traffic["grid"])
@@ -51,6 +67,16 @@ class Entry:
             plan, source = self.plan, "pinned by the traffic file"
         self.resolved = plan
         self.plan_text = f"dw{plan.d_w}.nf{plan.n_f} (plan_source {source})"
+
+    def compiled_call(self, compiler_options: dict):
+        """The timed call as one program compiled with `compiler_options`.
+
+        The traced run's phase session runs it (`chipbench.run`); the
+        window never does.
+        """
+        fn = jax.jit(_program(self.config, self.traffic),
+                     compiler_options=compiler_options)
+        return lambda state: fn(state, self.arrays)
 
     def call(self, state):
         """One timed call: dispatch, then wait for both levels."""
@@ -86,7 +112,6 @@ def lowerables(config: dict, traffic: dict, devices):
     import jax.numpy as jnp
 
     from repro.core import ir, registry
-    from repro.kernels import ops
 
     spec = ir.OPS[config["op"]]
     grid = tuple(traffic["grid"])
@@ -96,15 +121,9 @@ def lowerables(config: dict, traffic: dict, devices):
     n_arr = config["coefficients"]["arrays"]
     arrays = (jax.ShapeDtypeStruct((n_arr,) + grid, dt, sharding=sh)
               if n_arr else None)
-    scalars = tuple(config["coefficients"]["scalars"])
     plan = _plan_arg(traffic)
     shown, source = ((plan, "pinned") if plan != "auto" else
                      registry.resolve_plan(spec, grid,
                                            word_bytes=config["word_bytes"]))
-
-    def call(state, arrays):
-        return ops.mwd(spec, state, ir.join_coeffs(spec, arrays, scalars),
-                       traffic["steps_per_call"], plan=plan)
-
     label = f"ops.mwd dw{shown.d_w}.nf{shown.n_f} ({source})"
-    return [(label, jax.jit(call), (state, arrays))]
+    return [(label, jax.jit(_program(config, traffic)), (state, arrays))]

@@ -1,8 +1,10 @@
 """mwd_update_ps_per_lup: the MWD kernel's in-tile update time, in ps per LUP.
 
-Device time of the kernel's ``mwd.update`` regions (the T masked updates of
-a grid step with their iota and mask set-up), summed over the cell's chips,
-over the LUPs of the traced calls. None when the trace holds no complete
+Device time of the kernel's ``mwd.update`` regions (a grid step's in-tile
+updates: each time level whose rows meet the diamond and whose slab meets
+the interior, over the sublane tiles that cover its rows, with the level's
+bounds and masks), summed over the cell's chips, over the LUPs of the
+traced calls. None when the trace holds no complete
 regions (`chipbench.regions`).
 """
 

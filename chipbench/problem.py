@@ -5,12 +5,10 @@ parameters into ``(cur, prev)`` and the stacked coefficient arrays, already
 in the layout (and, on several chips, the sharding) the timed entry reads.
 Nothing is made on the host, so set-up does not grow with the grid.
 
-The draw kinds are the configuration file's ``coefficients.draw``:
-
-  diffusion      arrays[1:] ~ U(low, high) per cell, arrays[0] = 1 - their
-                 sum: a convex update, so fields stay bounded forever
-  wave_velocity  arrays[0] = c_max * (v / v_high)^2, v ~ U(v_low, v_high):
-                 the squared Courant number of a wave with velocity v
+The draw kind is the configuration file's ``coefficients.draw``. Each kind
+lives in a file of its own, ``chipbench/draws/<kind>.py``, which exposes
+``arrays(coef, key, shape, dtype)``; a new kind needs a new file there and
+no edit here.
 
 The seed may exceed 32 bits: its low and high words both enter the key.
 """
@@ -22,6 +20,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from chipbench import cells
+
 
 def seed_words(seed: int) -> tuple[int, int]:
     """(low, high) 32-bit words of a non-negative seed below 2**64."""
@@ -31,20 +31,10 @@ def seed_words(seed: int) -> tuple[int, int]:
 
 
 def _arrays(coef: dict, key, shape, dtype):
-    n = coef["arrays"]
-    if n == 0:
+    if coef["arrays"] == 0:
         return None
-    kind = coef["draw"]
-    if kind == "diffusion":
-        nb = jax.random.uniform(key, (n - 1,) + shape, dtype,
-                                coef["low"], coef["high"])
-        centre = 1.0 - jnp.sum(nb, axis=0, keepdims=True)
-        return jnp.concatenate([centre, nb], axis=0)
-    if kind == "wave_velocity":
-        v = jax.random.uniform(key, (n,) + shape, dtype, coef["v_low"],
-                               coef["v_high"])
-        return coef["c_max"] * (v / coef["v_high"]) ** 2
-    raise ValueError(f"unknown coefficient draw {kind!r}")
+    draw = cells.load_module("draws", coef["draw"])
+    return draw.arrays(coef, key, shape, dtype)
 
 
 def _draw(config: dict, shape, lo, hi):

@@ -1,11 +1,13 @@
 """Plain reference of the configurations' stencils, in jax.numpy.
 
-Written from the paper's listings (Malas et al., arXiv:1510.04995,
-Listings 2 and 3) and independent of the program under test: it imports
-nothing from it and takes no table, plan or coefficient the program made.
-Each step updates the interior and keeps the R-deep Dirichlet frame of the
-state it was given; a call of n steps returns the last two levels, as the
-program's entries do.
+Written from the paper's listings (Malas et al., arXiv:1510.04995) and
+independent of the program under test: it imports nothing from it and
+takes no table, plan or coefficient the program made. Each op's step lives
+in a file of its own, ``chipbench/reference/ops/<op>.py``, found by the
+configuration's ``op`` and exposing ``step(cur, prev, arrays, scalars)``;
+a new op needs a new file there and no edit here. Each step updates the
+interior and keeps the R-deep Dirichlet frame of the state it was given; a
+call of n steps returns the last two levels, as the program's entries do.
 
 `advance` runs in the dtype it is given: float32 is the reference, and
 bfloat16 is the lower-precision control that the comparison must reject.
@@ -18,12 +20,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from chipbench import cells
 
-def _core(a, r):
+
+def core(a, r):
+    """The interior of `a`: its R-deep frame cut away on every axis."""
     return a[r:-r, r:-r, r:-r]
 
 
-def _shift(a, r, axis, off):
+def shift(a, r, axis, off):
     """Core-sized view of `a` displaced by `off` along `axis` (|off| <= r)."""
     idx = []
     for ax in range(3):
@@ -32,51 +37,13 @@ def _shift(a, r, axis, off):
     return a[tuple(idx)]
 
 
-def step_7pt_var(cur, prev, arrays, scalars):
-    """Listing 2: U = c0*V + sum over the six axis neighbours of c_k*V_k.
-
-    arrays: (7, z, y, x) as [centre, z-, z+, y-, y+, x-, x+].
-    """
-    del prev, scalars
-    r = 1
-    out = _core(arrays[0], r) * _core(cur, r)
-    k = 1
-    for ax in range(3):
-        for o in (-1, 1):
-            out = out + _core(arrays[k], r) * _shift(cur, r, ax, o)
-            k += 1
-    return cur.at[r:-r, r:-r, r:-r].set(out)
-
-
-def step_25pt_const(cur, prev, arrays, scalars):
-    """Listing 3: U' = 2V - U + C * (c0*V + sum_d c_d * (6 neighbours at d)).
-
-    arrays: (1, z, y, x) holding C; scalars: (c0, c1, c2, c3, c4).
-    """
-    r = 4
-    c = scalars
-    lap = c[0] * _core(cur, r)
-    for d in range(1, 5):
-        acc = None
-        for ax in range(3):
-            for o in (-1, 1):
-                v = _shift(cur, r, ax, o * d)
-                acc = v if acc is None else acc + v
-        lap = lap + c[d] * acc
-    out = 2.0 * _core(cur, r) - _core(prev, r) + _core(arrays[0], r) * lap
-    return cur.at[r:-r, r:-r, r:-r].set(out)
-
-
-STEPS = {"7pt-var": step_7pt_var, "25pt-const": step_25pt_const}
-
-
 @partial(jax.jit, static_argnames=("op", "scalars", "n_steps", "dtype"))
 def advance(op: str, state, arrays, scalars: tuple, n_steps: int, dtype):
     """n_steps of `op` from (cur, prev) in `dtype`; returns float32 levels.
 
     Returns (level n, level n-1), the pair the program's entries return.
     """
-    step = STEPS[op]
+    step = cells.load_module("reference/ops", op).step
     dt = jnp.dtype(dtype)
     cur, prev = (s.astype(dt) for s in state)
     arrays = arrays.astype(dt) if arrays is not None else None

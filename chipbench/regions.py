@@ -1,14 +1,15 @@
-"""Read the program's own profiler names out of a traced run's xplane.
+"""Read the program's own profiler names out of a traced run's xplanes.
 
-`chipbench.trace` reduces a traced window to device ops and the benchmark's
-``bench.*`` host spans. The program names more than that, and this module
-reads it from the same file, on the same clock:
+`chipbench.trace` reduces a traced window to device ops and host spans.
+The program names more than that, and this module reads it, on the same
+clock:
 
   regions     inside the MWD kernel, one per phase of a grid step:
               ``mwd.fetch`` (inbound slab DMAs), ``mwd.shift`` (the window
-              shift), ``mwd.update`` (the T masked updates) and
-              ``mwd.emit`` (outbound slab DMAs, on the steps that emit).
-              Mosaic turns each ``jax.named_scope`` of the kernel into a
+              shift), ``mwd.update`` (the in-tile updates of the time
+              levels whose rows meet the diamond) and ``mwd.emit``
+              (outbound slab DMAs, on the steps that emit). Mosaic turns
+              each ``jax.named_scope`` of the kernel into a
               ``tpu.trace_start``/``trace_stop`` pair; each region event is
               assigned to the kernel event (``mwd_*`` on ``XLA Ops``) of
               its device that covers it.
@@ -18,26 +19,26 @@ reads it from the same file, on the same clock:
               expose metadata stats, so `op_scopes` reads them from the
               file's protobuf wire format.
   host spans  ``repro.*`` (``repro.mwd``, ``repro.mwd.plan``,
-              ``repro.mwd.launch``) beside ``bench.*``.
+              ``repro.mwd.launch``) beside ``bench.*``, as
+              `chipbench.trace` reads them.
 
-The regions reach the trace only when libtpu is started with
-``--xla_enable_custom_call_region_trace=true`` (on the device plane's
-``XLA TraceMe`` line). Importing this module appends that flag to
-``LIBTPU_INIT_ARGS`` when the process is a traced run of ``run.py``
-(``--trace 1``): the harness loads the metric readers, and with them this
-module, before JAX starts its backend. Untraced runs keep the environment
-as it is, and since JAX's compile cache key holds ``LIBTPU_INIT_ARGS``, the
-two kinds of run never share a compiled program.
-
-`chipbench.trace` reads only the ``XLA Ops`` line, so region events never
-enter its op sweep and its metrics read the same with regions on. Each
-traced run prints, before its result line, the regions per kernel event,
-the kernel time no region covers (split into the head, the grid-step
-boundaries, the gaps between a step's phases and the tail) and the idle
-time by innermost host span. A reader gets None, never a
-smaller number, when the regions cannot be trusted: a kernel event whose
-fetch, shift and update counts differ, whose regions last longer than it,
-or whose counts differ from another call's (dropped events).
+The regions reach the trace (each device plane's ``XLA TraceMe`` line)
+only from a program compiled with `REGION_OPTION`. Such a program also
+leaves an event for every block of code its kernel enters, on the plane's
+``Tensor Core`` line: millions per call of a kernel that branches per time
+level, which fill the profiler's trace buffers within two or three calls,
+after which the device's later events are lost (whole calls, and regions
+of the call it cuts). So the traced window runs the program as it is timed
+and holds no regions; the regions come from a phase session of their own
+(`chipbench.run`): after the window and the check, the same call compiled
+with `REGION_OPTION` runs `run.PHASE_CALLS` times, each call profiled
+alone. A reader gets None, never a smaller number, when the regions cannot
+be trusted: a kernel event whose fetch, shift and update counts differ,
+whose regions last longer than it, or whose counts differ from another
+call's (dropped events). Each traced run prints, before its result line,
+the regions per kernel event, the kernel time no region covers (split into
+the head, the grid-step boundaries, the gaps between a step's phases and
+the tail) and the idle time of the window by innermost host span.
 """
 
 from __future__ import annotations
@@ -47,7 +48,6 @@ import dataclasses
 import glob
 import os
 import re
-import sys
 
 from chipbench import trace
 
@@ -57,37 +57,10 @@ TRACES = os.path.join(os.path.dirname(HERE), ".chipbench_out", "trace")
 REGION_LINE = "XLA TraceMe"            # of each /device:TPU:<n> plane
 REGIONS = ("mwd.fetch", "mwd.shift", "mwd.update", "mwd.emit")
 PER_STEP = ("mwd.fetch", "mwd.shift", "mwd.update")   # once per grid step
-HOST_PREFIXES = ("repro.", trace.SPAN_PREFIX)
 ENTRY_SPAN = "repro.mwd"
 _SCOPE = re.compile(r"(?:^|/)(mwd\.[a-z_]+)(?=/|$)")
-REGION_FLAG = "--xla_enable_custom_call_region_trace=true"
-
-
-def traced_run(argv: list) -> bool:
-    """Whether `argv` runs ``chipbench/run.py`` with ``--trace 1``."""
-    if not argv or os.path.basename(argv[0]) != "run.py":
-        return False
-    args = list(argv[1:])
-    return any(a == "--trace=1" or (a == "--trace" and args[i + 1:i + 2]
-                                    == ["1"]) for i, a in enumerate(args))
-
-
-def enable_regions(argv: list, environ) -> bool:
-    """Append `REGION_FLAG` to ``LIBTPU_INIT_ARGS`` for a traced run.
-
-    The variable is never overwritten: the chip's machine may set it.
-    Returns whether the flag is now set.
-    """
-    args = environ.get("LIBTPU_INIT_ARGS", "")
-    if REGION_FLAG in args.split():
-        return True
-    if not traced_run(argv):
-        return False
-    environ["LIBTPU_INIT_ARGS"] = (args + " " + REGION_FLAG).strip()
-    return True
-
-
-enable_regions(sys.argv, os.environ)
+# compile option of the phase session's program (see the docstring)
+REGION_OPTION = {"xla_enable_custom_call_region_trace": True}
 
 
 @dataclasses.dataclass
@@ -150,13 +123,10 @@ def reduce_planes(planes) -> Regions:
     """
     base = trace.reduce_planes(planes)
     t0, t1 = base.window
-    kernels, regions, spans = {}, {}, []
+    kernels, regions = {}, {}
     for plane in planes:
         m = trace.DEVICE_PLANE.match(plane.name)
         if not m:
-            spans += [(float(e.start_ns), float(e.start_ns + e.duration_ns),
-                       e.name) for line in plane.lines for e in line.events
-                      if e.name.startswith(HOST_PREFIXES)]
             continue
         dev = int(m.group(1))
         for line in plane.lines:
@@ -184,16 +154,16 @@ def reduce_planes(planes) -> Regions:
             if i >= 0 and e <= ks[i].end:
                 ks[i].add(s, e, reg)
         out += ks
-    return Regions(kernels=out, scopes={},
-                   spans=sorted(spans), window=base.window)
+    return Regions(kernels=out, scopes={}, spans=base.spans,
+                   window=base.window)
 
 
-def trusted(reg: Regions) -> bool:
+def trusted(kernels: list) -> bool:
     """Whether every kernel event's regions are complete (module docstring)."""
-    if not reg.kernels:
+    if not kernels:
         return False
-    first = reg.kernels[0].count
-    for k in reg.kernels:
+    first = kernels[0].count
+    for k in kernels:
         n = k.count.get(PER_STEP[0], 0)
         if n == 0 or any(k.count.get(r, 0) != n for r in PER_STEP):
             return False
@@ -296,7 +266,7 @@ def load(path: str) -> Regions:
 
 
 def for_run(run, traces: str = TRACES) -> Regions | None:
-    """The regions of `run`'s traced window, or None.
+    """The program's names in `run`'s traced window, or None.
 
     The run wrote its xplane under `traces`; the newest one is taken and
     used only if its window is the one `run.trace` reduced.
@@ -315,12 +285,14 @@ def for_run(run, traces: str = TRACES) -> Regions | None:
 
 
 def region_ps_per_lup(run, names) -> float | None:
-    """Device time of regions `names`, summed over chips, in ps per LUP."""
-    reg = for_run(run)
-    if reg is None or not trusted(reg) or not run.calls:
+    """Device time of regions `names` in the phase session, ps per LUP.
+
+    Summed over chips, over the LUPs of the session's calls.
+    """
+    if not run.phases or not trusted(run.phases) or not run.phase_lups:
         return None
-    ns = sum(k.ns.get(n, 0.0) for k in reg.kernels for n in names)
-    return ns * 1e3 / sum(c[2] for c in run.calls)
+    ns = sum(k.ns.get(n, 0.0) for k in run.phases for n in names)
+    return ns * 1e3 / run.phase_lups
 
 
 def scope_ns(run, reg: Regions, scope: str) -> float:
@@ -335,15 +307,6 @@ def entry_spans(reg: Regions) -> list:
     return [s for s in reg.spans if s[2] == ENTRY_SPAN and t0 <= s[0] < t1]
 
 
-def span_at(spans: list, t: float) -> str:
-    """Innermost host span covering `t` (latest start, then shortest)."""
-    best = None
-    for s, e, n in spans:
-        if s <= t < e and (best is None or (s, -e) >= best[0]):
-            best = ((s, -e), n)
-    return best[1] if best else "no host span"
-
-
 def gap_names(run, reg: Regions) -> dict:
     """Idle time of the window by the innermost host span, in ns per chip.
 
@@ -356,7 +319,7 @@ def gap_names(run, reg: Regions) -> dict:
             cuts = sorted({s, e} | {t for a, b, _ in reg.spans
                                     for t in (a, b) if s < t < e})
             for a, b in zip(cuts, cuts[1:]):
-                name = span_at(reg.spans, (a + b) / 2)
+                name = trace.host_span_at(reg.spans, (a + b) / 2)
                 out[name] = (out.get(name, 0.0)
                              + (b - a) / len(run.attributions))
     return out
@@ -365,28 +328,35 @@ def gap_names(run, reg: Regions) -> dict:
 _REPORTED = set()
 
 
+def report_phases(kernels: list) -> None:
+    """Print the phase session's regions per kernel event."""
+    print(f"regions: {len(kernels)} kernel events; trusted "
+          f"{trusted(kernels)}", flush=True)
+    if not kernels:
+        return
+
+    ks = kernels
+
+    def ms(values):      # mean over kernel events, in ms
+        return f"{sum(values) / len(ks) * 1e-6:.6g}"
+
+    print(f"regions per kernel event: counts {ks[0].count}; ms "
+          + ", ".join(f"{r} {ms(k.ns.get(r, 0.0) for k in ks)}"
+                      for r in REGIONS)
+          + f"; kernel {ms(k.end - k.start for k in ks)}, no region "
+          + f"{ms(k.uncovered_ns for k in ks)} ("
+          + ", ".join(f"{g} {ms(k.gaps.get(g, 0.0) for k in ks)}"
+                      for g in ("head", "step", "phase", "tail")) + ")",
+          flush=True)
+
+
 def report(run, reg: Regions) -> None:
-    """Print the phase split and the named gaps, once per trace."""
+    """Print the window's idle time by host span, once per trace."""
     key = tuple(reg.window)
     if key in _REPORTED:
         return
     _REPORTED.add(key)
     n = max(len(run.calls), 1)
-    print(f"regions: {len(reg.kernels)} kernel events; trusted "
-          f"{trusted(reg)}", flush=True)
-    ks = reg.kernels
-    if ks:
-        def ms(values):      # mean over kernel events, in ms
-            return f"{sum(values) / len(ks) * 1e-6:.6g}"
-
-        print(f"regions per kernel event: counts {ks[0].count}; ms "
-              + ", ".join(f"{r} {ms(k.ns.get(r, 0.0) for k in ks)}"
-                          for r in REGIONS)
-              + f"; kernel {ms(k.end - k.start for k in ks)}, no region "
-              + f"{ms(k.uncovered_ns for k in ks)} ("
-              + ", ".join(f"{g} {ms(k.gaps.get(g, 0.0) for k in ks)}"
-                          for g in ("head", "step", "phase", "tail")) + ")",
-              flush=True)
     gaps = gap_names(run, reg)
     entries = entry_spans(reg)
     idle = sum(gaps.values()) / n * 1e-6

@@ -19,7 +19,11 @@ In order, the run
   5. reads the peak device memory, then checks the window's last call
      against the plain float32 reference (`chipbench.reference`) run on
      that call's own input;
-  6. prints the cell's end-to-end metrics (--trace 0) or per-layer metrics
+  6. with --trace 1, runs the phase session: the same call compiled with
+     the kernel's profiler regions, PHASE_CALLS times, each profiled alone
+     (`chipbench.regions` says why the window cannot hold them); if it
+     fails, the phase metrics are left out and the traceback printed;
+  7. prints the cell's end-to-end metrics (--trace 0) or per-layer metrics
      (--trace 1) as the last stdout line, one JSON object, and the numbers
      compared, each beside its limit, as the last stderr lines.
 
@@ -37,10 +41,12 @@ import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+import traceback  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 OUT = os.path.join(ROOT, ".chipbench_out")     # traces; gitignored
+PHASE_CALLS = 2       # of the phase session: two, so their counts can agree
 
 
 class NoChip(Exception):
@@ -60,6 +66,8 @@ class Run:
     peak_bytes: int | None = None
     trace: object = None         # chipbench.trace.Trace of the window
     attributions: list | None = None
+    phases: list | None = None   # chipbench.regions.Kernel of the phase session
+    phase_lups: int = 0          # LUPs of the phase session's calls
 
     @property
     def window_ns(self) -> float:
@@ -164,6 +172,39 @@ def traced_window(entry, state, seconds: float, workload: str):
     return res, trace.load(path)
 
 
+def phase_session(entry, state):
+    """Kernel events with regions of PHASE_CALLS region-compiled calls.
+
+    Each call starts from `state` and its output is dropped, so no more is
+    alive than in the window; each runs under a profiler session of its
+    own, whose data is read in memory (`trace.profile`): a call of a
+    kernel that branches per time level leaves a hundred MB of events.
+    Returns (kernel events, LUPs of their calls); (None, 0) when the entry
+    adapter cannot compile its call with options (no ``compiled_call``).
+    """
+    import jax
+
+    from chipbench import regions, trace
+
+    if not hasattr(entry, "compiled_call"):
+        return None, 0
+    call = entry.compiled_call(regions.REGION_OPTION)
+    jax.block_until_ready(call(state))            # compiles or loads it
+
+    def one_call():
+        with jax.profiler.TraceAnnotation("bench.call"):
+            out = call(state)
+        with jax.profiler.TraceAnnotation("bench.wait"):
+            jax.block_until_ready(out)
+
+    kernels = []
+    for _ in range(PHASE_CALLS):
+        data = trace.profile(one_call)
+        kernels += regions.reduce_planes(list(data.planes)).kernels
+        del data
+    return kernels, PHASE_CALLS * entry.lups_per_call
+
+
 def check(cell, arrays, last_in, last_out) -> dict:
     """The compared numbers of the window's last call, with their limits."""
     from chipbench import compare
@@ -183,7 +224,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
     """Run `cell` (see the module docstring); the result line as a dict."""
     import jax
 
-    from chipbench import cells, problem
+    from chipbench import cells, problem, regions
     from chipbench import trace as tracemod
     from repro import compile_cache
 
@@ -231,6 +272,16 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
 
     compared = check(cell, arrays, last_in, last_out)
     correct = all(v["value"] <= v["limit"] for v in compared.values())
+    if traced:
+        del last_in
+        try:
+            run.phases, run.phase_lups = phase_session(entry, last_out)
+        except Exception:        # noqa: BLE001 - the phase readers read None
+            traceback.print_exc()
+            log("phase session failed (traceback on stderr): the phase "
+                "metrics are left out of this run")
+        if run.phases is not None:
+            regions.report_phases(run.phases)
 
     readers = cell.per_layer if traced else cell.end_to_end
     metrics = {}

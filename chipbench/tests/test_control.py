@@ -10,7 +10,9 @@ At sizes a CPU test can hold, with the program's kernels interpreted:
   the cell can have: a call that returns its state unchanged, an answer
   altered where it is produced, and (on a four-device mesh, for the
   distributed cell PERF.md keeps under Open questions) the exchange
-  between chips left out. The same run unbroken reports ``correct: true``.
+  between chips left out. The same run unbroken reports ``correct: true``;
+* a traced run whose phase session fails still reports, with the phase
+  metrics left out.
 
 The chip readings the limits were set from are in PERF.md; `limits.py`
 takes them on the chip at the cells' own sizes.
@@ -23,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from chipbench import cells, compare, problem, run
+from chipbench import cells, compare, problem, run, trace
 from chipbench.reference import stencils
 
 SMALL = {"7pt-var-f32": ("7pt-var.n512.t64", [12, 16, 24], 4),
@@ -134,3 +136,27 @@ def test_four_chip_run_reports_faults(monkeypatch, fault):
         monkeypatch.setattr(halo, "exchange_2d", _local_pad)
     res = _run(monkeypatch, cell)
     assert res["correct"] is (fault == "none"), res["compared"]
+
+
+def test_traced_run_survives_a_failed_phase_session(monkeypatch, capsys):
+    def traced_window(entry, state, seconds, workload):
+        calls, last_in, last_out = run.window(entry, state, seconds)
+        kernel = (0.0, 5e8, "mwd_7pt-var.1", trace.KERNEL)
+        tr = trace.Trace(devices=[trace.Device("/device:TPU:0", [kernel])],
+                         spans=[], window=(0.0, 1e9))
+        return (calls, last_in, last_out), tr
+
+    def phase_session(entry, state):
+        raise RuntimeError("the profiler gave up")
+
+    monkeypatch.setattr(run, "traced_window", traced_window)
+    monkeypatch.setattr(run, "phase_session", phase_session)
+    monkeypatch.setattr(run, "check_devices", lambda chips: jax.devices())
+    monkeypatch.setattr(cells, "load_peaks", lambda kind: {
+        "flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
+    res = run.run_cell(_small_cell(*SMALL["7pt-var-f32"]), 2 ** 33 + 5, 0.0,
+                       True)
+    assert res["correct"] is True, res["compared"]
+    assert res["metrics"]["idle_pct"]["value"] == 50.0
+    assert not any(m.endswith("_ps_per_lup") for m in res["metrics"])
+    assert "the profiler gave up" in capsys.readouterr().err

@@ -101,6 +101,11 @@ def _break(bench, root, what):
         t = json.loads(path.read_text())
         t["entry"] = "no_such_entry"
         path.write_text(json.dumps(t))
+    elif what in ("reference", "draw"):
+        cfg = _config(w["config"])
+        os.remove(root / "chipbench" / (
+            f"reference/ops/{cfg['op']}.py" if what == "reference"
+            else f"draws/{cfg['coefficients']['draw']}.py"))
     elif what == "metric":
         bench["end_to_end"].append({"name": "no_such_metric", "unit": "s",
                                     "better": "lower", "bound": 0.1,
@@ -109,7 +114,7 @@ def _break(bench, root, what):
 
 
 @pytest.mark.parametrize("what", ["workload", "config", "traffic", "entry",
-                                  "metric"])
+                                  "metric", "reference", "draw"])
 def test_unresolved_name_fails_before_device_work(tmp_path, what):
     root = _copy_tree(tmp_path)
     bench = _bench()

@@ -38,7 +38,7 @@ def trim(space):
                         and names.get(e.metadata_id) in regions.REGIONS]
             else:
                 kept = [e for e in line.events if names.get(
-                    e.metadata_id, "").startswith(regions.HOST_PREFIXES)]
+                    e.metadata_id, "").startswith(trace.SPAN_PREFIXES)]
             if kept:
                 del line.events[:]
                 line.events.extend(kept)

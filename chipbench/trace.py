@@ -13,7 +13,8 @@ classified as
   glue        every other device op (pad, frame sync, crop, copies, ...)
 
 named by their HLO instruction name (`op_name`), and the host spans the
-benchmark writes (``bench.call``, ``bench.wait``).
+benchmark writes (``bench.call``, ``bench.wait``) and the program writes
+(``repro.mwd``, ``repro.mwd.plan``, ...).
 The traced window runs from the first ``bench.call`` start to the last
 ``bench.wait`` end. Device time is attributed by sweeping the window: each
 instant an op runs counts once, for the innermost (latest-started) event
@@ -38,7 +39,7 @@ _COLLECTIVE = re.compile(
     r"collective-broadcast|ragged-all-to-all|\bsend\b|\brecv\b|send-done|"
     r"recv-done)", re.IGNORECASE)
 OPS_LINE = "XLA Ops"
-SPAN_PREFIX = "bench."
+SPAN_PREFIXES = ("bench.", "repro.")     # the benchmark's, the program's
 
 
 def op_name(event_name: str) -> str:
@@ -126,7 +127,7 @@ def reduce_planes(planes) -> Trace:
             continue
         for line in plane.lines:
             for e in line.events:
-                if e.name.startswith(SPAN_PREFIX):
+                if e.name.startswith(SPAN_PREFIXES):
                     start = float(e.start_ns)
                     spans.append((start, start + float(e.duration_ns),
                                   e.name))
@@ -191,19 +192,20 @@ def attribute(dev: Device, window: tuple) -> Attribution:
 
 
 def host_span_at(spans: list, t: float) -> str:
-    """Name of the innermost host span covering `t`, or "no bench span"."""
+    """Innermost host span covering `t` (latest start, then shortest)."""
     best = None
     for s, e, n in spans:
-        if s <= t < e and (best is None or s >= best[0]):
-            best = (s, n)
-    return best[1] if best else "no bench span"
+        if s <= t < e and (best is None or (s, -e) >= best[0]):
+            best = ((s, -e), n)
+    return best[1] if best else "no host span"
 
 
 def breakdown(tr: Trace, atts: list, top: int = 10) -> dict:
     """Top device ops by self time and the longest idle gaps, in seconds.
 
     Op times are means over the devices; each gap (on any device) is named
-    by the host span active at its midpoint.
+    by the innermost host span at its midpoint, a ``repro.*`` span of the
+    program where one covers it.
     """
     ops = {}
     for att in atts:
@@ -217,6 +219,23 @@ def breakdown(tr: Trace, atts: list, top: int = 10) -> dict:
     gaps.sort(key=lambda g: -g[0])
     return {"device_ops": [[n, ns * 1e-9] for n, ns in top_ops],
             "idle_gaps": [[n, ns * 1e-9] for ns, n in gaps[:top]]}
+
+
+def profile(fn) -> "ProfileData":
+    """Run ``fn()`` under a profiler session of its own; the session's data.
+
+    The data stays in memory: nothing is exported or written to disk.
+    (`jax.profiler` exposes no public call that returns a session's data;
+    jaxlib's session does.)
+    """
+    from jax._src.lib import _profiler
+
+    sess = _profiler.ProfilerSession(capture_options())
+    try:
+        fn()
+    finally:
+        data = sess.stop_and_get_profile_data()
+    return data
 
 
 def capture_options():
