@@ -78,21 +78,33 @@ def metrics_for(entries: list, workload: str, root: str = ROOT) -> dict:
     return out
 
 
+def load_bench(root: str = ROOT) -> dict:
+    """``<root>/BENCHMARK.json``."""
+    return _load_json(os.path.join(root, "BENCHMARK.json"))
+
+
+def load_config(name: str, bench: dict | None = None,
+                root: str = ROOT) -> dict:
+    """The file of configuration `name` of `bench` (default: load_bench)."""
+    if bench is None:
+        bench = load_bench(root)
+    configs = {c["name"]: c for c in bench["configs"]}
+    if name not in configs:
+        raise CellError(f"no config {name!r} in BENCHMARK.json")
+    return _load_json(os.path.join(root, configs[name]["file"]))
+
+
 def load_cell(workload: str, bench: dict | None = None,
               root: str = ROOT) -> Cell:
-    """Resolve `workload` of `bench` (default: ROOT/BENCHMARK.json)."""
+    """Resolve `workload` of `bench` (default: load_bench)."""
     if bench is None:
-        bench = _load_json(os.path.join(root, "BENCHMARK.json"))
+        bench = load_bench(root)
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise CellError(f"no workload {workload!r} in BENCHMARK.json "
                         f"(have {sorted(cells)})")
     w = cells[workload]
-    configs = {c["name"]: c for c in bench["configs"]}
-    if w["config"] not in configs:
-        raise CellError(f"workload {workload!r} names config "
-                        f"{w['config']!r}, which BENCHMARK.json lacks")
-    config = _load_json(os.path.join(root, configs[w["config"]]["file"]))
+    config = load_config(w["config"], bench, root)
     traffic = _load_json(os.path.join(root, "chipbench", "traffic",
                                       f"{w['traffic']}.json"))
     if traffic["chips"] != w["chips"]:

@@ -81,8 +81,7 @@ def main(argv=None) -> int:
     config.interpret = lambda: False      # Mosaic, not the CPU interpreter
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    bench = cells.load_bench()
     names = args.workloads or [w["name"] for w in bench["workloads"]]
     failed = 0
     for name in names:

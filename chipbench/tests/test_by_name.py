@@ -3,11 +3,16 @@
 * The cells of BENCHMARK.json draw and check exactly as they did when both
   lived in a table of `problem.py` and `reference/stencils.py`: that code is
   kept below as the oracle, and the seeded draw and a reference call are
-  bitwise equal to it.
+  bitwise equal to it, for every configuration whose op and draw kind it
+  holds.
+* Every configuration, known to the oracle or not, draws the same problem
+  from the same seed, of the shapes its file states, and its reference
+  step moves the field and keeps it finite.
 * A configuration whose op and draw kind the tree lacks joins with new
   files and new entries of BENCHMARK.json alone: in a copy of the tree,
   Listing 4's 25-point variable-coefficient stencil with a draw kind of its
-  own resolves, and a run of it on the CPU agrees with its reference.
+  own resolves, gets every per-layer reader a cell of the same entry
+  adapter gets, and a run of it on the CPU agrees with its reference.
 """
 
 import hashlib
@@ -103,6 +108,7 @@ def _old_step_25pt_const(cur, prev, arrays, scalars):
 
 _OLD_STEPS = {"7pt-var": _old_step_7pt_var,
               "25pt-const": _old_step_25pt_const}
+_OLD_DRAWS = ("diffusion", "wave_velocity")
 
 
 @partial(jax.jit, static_argnames=("op", "scalars", "n_steps", "dtype"))
@@ -121,27 +127,27 @@ def _old_advance(op, state, arrays, scalars, n_steps, dtype):
     return cur.astype(jnp.float32), prev.astype(jnp.float32)
 
 
-def _bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
-
-
-def _config(name):
-    entry = {c["name"]: c for c in _bench()["configs"]}[name]
-    with open(os.path.join(ROOT, entry["file"])) as f:
-        return json.load(f)
-
-
 def _equal(a, b):
     return all(np.array_equal(np.asarray(x), np.asarray(y), equal_nan=True)
                for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
 
 
+def _names():
+    return sorted(c["name"] for c in cells.load_bench()["configs"])
+
+
+def _in_the_oracle(name):
+    """Whether the old code held `name`'s op and coefficient draw."""
+    cfg = cells.load_config(name)
+    coef = cfg["coefficients"]
+    return cfg["op"] in _OLD_STEPS and (coef["arrays"] == 0
+                                        or coef["draw"] in _OLD_DRAWS)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("name", sorted(c["name"]
-                                        for c in _bench()["configs"]))
+@pytest.mark.parametrize("name", [n for n in _names() if _in_the_oracle(n)])
 def test_draw_and_reference_equal_the_code_they_replaced(name, dtype):
-    cfg = _config(name)
+    cfg = cells.load_config(name)
     grid, seed = (12, 16, 24), 2 ** 33 + 29
     lo, hi = problem.seed_words(seed)
     state, arrays = problem.draw(cfg, grid, seed)
@@ -153,6 +159,25 @@ def test_draw_and_reference_equal_the_code_they_replaced(name, dtype):
     got = stencils.advance(*args)
     assert _equal(got, _old_advance(*args))
     assert not _equal(got, state)       # the steps did move the field
+
+
+@pytest.mark.parametrize("name", _names())
+def test_every_config_draws_and_steps(name):
+    """Any configuration, whether or not the oracle above knows its op."""
+    cfg = cells.load_config(name)
+    grid, seed = (4 * cfg["radius"] + 4, 16, 24), 2 ** 33 + 41
+    state, arrays = problem.draw(cfg, grid, seed)
+    assert _equal((state, arrays), problem.draw(cfg, grid, seed))
+    assert not _equal(state, problem.draw(cfg, grid, seed + 1)[0])
+    assert all(s.shape == grid for s in state)
+    n = cfg["coefficients"]["arrays"]
+    assert (arrays is None) if n == 0 else arrays.shape == (n, *grid)
+    got = stencils.advance(cfg["op"], state, arrays,
+                           tuple(cfg["coefficients"]["scalars"]), 4,
+                           "float32")
+    assert all(bool(jnp.isfinite(x).all())
+               for x in jax.tree.leaves((state, arrays, got)))
+    assert not _equal(got, state)
 
 
 # --- a new op and draw kind, as files and entries alone
@@ -238,7 +263,11 @@ def test_new_op_joins_with_new_files_only(tmp_path):
     for rel in NEW_FILES:               # the copy lacks them, whatever the tree
         if os.path.exists(root / rel):
             os.remove(root / rel)
-    bench = _bench()
+    bench = cells.load_bench()          # less any 25pt-var-f32 it holds
+    bench["configs"] = [c for c in bench["configs"]
+                        if c["name"] != "25pt-var-f32"]
+    bench["workloads"] = [w for w in bench["workloads"]
+                          if w["config"] != "25pt-var-f32"]
     bench["configs"].append({"name": "25pt-var-f32", "source": "-",
                              "file": "chipbench/configs/25pt-var-f32.json",
                              "reduced": [], "why": "-"})
@@ -256,7 +285,12 @@ def test_new_op_joins_with_new_files_only(tmp_path):
         (root / rel).write_text(textwrap.dedent(text).lstrip())
     after = _hashes(root)
     assert {k: v for k, v in after.items() if k in before} == before
-    assert cells.load_cell(NEW_CELL, root=str(root)).config["op"] == NEW_OP
+    new = cells.load_cell(NEW_CELL, root=str(root))
+    assert new.config["op"] == NEW_OP
+    for w in bench["workloads"]:        # the per-layer readers of the others
+        cell = cells.load_cell(w["name"], root=str(root))
+        if cell.traffic["entry"] == new.traffic["entry"]:
+            assert set(cell.per_layer) <= set(new.per_layer), w["name"]
 
     proc = subprocess.run(
         [sys.executable, "-c", _RUN, NEW_CELL], cwd=root, capture_output=True,

@@ -4,22 +4,22 @@ At sizes a CPU test can hold, with the program's kernels interpreted:
 
 * the program passes each configuration's limit, and the lower-precision
   control (the reference computed in bfloat16 in the program's place) and a
-  state returned unchanged both fail it;
-* a whole run of each kind of cell, with the chip check skipped and the
-  timed path broken underneath, reports ``correct: false`` for every fault
-  the cell can have: a call that returns its state unchanged, an answer
-  altered where it is produced, and (on a four-device mesh, for the
-  distributed cell PERF.md keeps under Open questions) the exchange
-  between chips left out. The same run unbroken reports ``correct: true``;
+  state returned unchanged both fail it. Every configuration of
+  BENCHMARK.json with a one-chip cell is tested, at SMALL's grid where it
+  names one and at a grid made from its radius otherwise;
+* a whole run of each kind of cell (of every such configuration), with the
+  chip check skipped and the timed path broken underneath, reports
+  ``correct: false`` for every fault the cell can have: a call that
+  returns its state unchanged, an answer altered where it is produced, and
+  (on a four-device mesh, for the distributed cell PERF.md keeps under
+  Open questions) the exchange between chips left out. The same run
+  unbroken reports ``correct: true``;
 * a traced run whose phase session fails still reports, with the phase
   metrics left out.
 
 The chip readings the limits were set from are in PERF.md; `limits.py`
 takes them on the chip at the cells' own sizes.
 """
-
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -33,13 +33,31 @@ SMALL = {"7pt-var-f32": ("7pt-var.n512.t64", [12, 16, 24], 4),
 DIST = ("25pt-const.n1024.dd4", [16, 64, 24], 4)
 
 
+def _one_chip_configs():
+    return sorted({w["config"] for w in cells.load_bench()["workloads"]
+                   if w["chips"] == 1})
+
+
+def _small(config):
+    """(a one-chip workload of `config`, a grid a CPU test holds, steps).
+
+    SMALL's where it names `config`; otherwise z = 4R + 4 (room for the
+    radius R) and SMALL's y and x at radius 4.
+    """
+    if config in SMALL:
+        return SMALL[config]
+    workload = next(w["name"] for w in cells.load_bench()["workloads"]
+                    if w["config"] == config and w["chips"] == 1)
+    radius = cells.load_config(config)["radius"]
+    return workload, [4 * radius + 4, 24, 24], 4
+
+
 def _small_cell(workload, grid, steps, **traffic):
     bench = None
     if workload == DIST[0]:
         # the four-chip cell is not in BENCHMARK.json yet (PERF.md, Open
         # questions); its traffic file and entry adapter are
-        with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
-            bench = json.load(f)
+        bench = cells.load_bench()
         bench["workloads"].append({"name": DIST[0], "config": "25pt-const-f32",
                                    "traffic": "n1024.dd4", "chips": 4,
                                    "why": "-"})
@@ -49,9 +67,9 @@ def _small_cell(workload, grid, steps, **traffic):
     return cell
 
 
-@pytest.mark.parametrize("config", sorted(SMALL))
+@pytest.mark.parametrize("config", _one_chip_configs())
 def test_program_passes_and_control_fails(config):
-    workload, grid, steps = SMALL[config]
+    workload, grid, steps = _small(config)
     cell = _small_cell(workload, grid, steps)
     cfg = cell.config
     limit = cfg["correct"]["limit"]
@@ -104,11 +122,11 @@ ONE_CHIP_FAULTS = ["none", "unchanged", "altered"]
 
 
 @pytest.mark.parametrize("fault", ONE_CHIP_FAULTS)
-@pytest.mark.parametrize("config", sorted(SMALL))
+@pytest.mark.parametrize("config", _one_chip_configs())
 def test_one_chip_run_reports_faults(monkeypatch, config, fault):
     from repro.kernels import ops
 
-    cell = _small_cell(*SMALL[config])
+    cell = _small_cell(*_small(config))
     if fault == "unchanged":
         monkeypatch.setattr(ops, "mwd", _unchanged)
     elif fault == "altered":

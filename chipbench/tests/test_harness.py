@@ -19,43 +19,46 @@ ROOT = cells.ROOT
 BENCH = os.path.join(ROOT, "BENCHMARK.json")
 
 
-def _bench():
-    with open(BENCH) as f:
-        return json.load(f)
-
-
-def _config(name):
-    entry = {c["name"]: c for c in _bench()["configs"]}[name]
-    with open(os.path.join(ROOT, entry["file"])) as f:
-        return json.load(f)
-
-
 # the paper's Table 1 FLOPs per LUP, and the arrays a call must touch:
-# every input read once, both returned levels written once
+# every input read once, both returned levels written once (a cross-check
+# of the IR's counts, which test_work_counts_match_the_ir holds every
+# configuration to)
 HAND = {"7pt-var-f32": (13, 8 + 2), "25pt-const-f32": (33, 3 + 2)}
 
 
 @pytest.mark.parametrize("name", sorted(HAND))
 def test_work_counts_match_hand_counts(name):
-    cfg = _config(name)
+    cfg = cells.load_config(name)
     flops, arrays = HAND[name]
     assert cfg["useful_flops_per_lup"] == flops
     a = cfg["compulsory_arrays_per_call"]
     assert a["read"] + a["write"] == arrays
-    # the IR's own count agrees with the paper's
+
+
+@pytest.mark.parametrize("name", sorted(
+    c["name"] for c in cells.load_bench()["configs"]))
+def test_work_counts_match_the_ir(name):
+    """The counts `mwd_roofline_pct` divides by, for any configuration."""
     from repro.core import ir
-    assert ir.OPS[cfg["op"]].flops_per_lup == flops
+    cfg = cells.load_config(name)
+    op = ir.OPS[cfg["op"]]
+    assert cfg["useful_flops_per_lup"] == op.flops_per_lup
+    assert (cfg["time_order"], cfg["radius"]) == (op.time_order, op.radius)
+    assert cfg["coefficients"]["arrays"] == op.n_coeff_arrays
+    a = cfg["compulsory_arrays_per_call"]
+    assert a["read"] == op.n_coeff_arrays + op.time_order
+    assert a["write"] == 2
 
 
 def test_roofline_least_time_by_hand():
     """7pt-var 512^3 x64 is bound by its 10 arrays of bytes at 819 GB/s."""
-    cfg = _config("7pt-var-f32")
+    cfg = cells.load_config("7pt-var-f32")
     traffic = {"grid": [512, 512, 512], "steps_per_call": 64}
     peaks = cells.load_peaks("TPU v5 lite")
     t = mwd_roofline_pct.least_time_s(cfg, traffic, peaks, n_calls=3,
                                       chips=1)
     assert t == pytest.approx(3 * 10 * 512 ** 3 * 4 / 819e9, rel=1e-12)
-    cfg = _config("25pt-const-f32")
+    cfg = cells.load_config("25pt-const-f32")
     t = mwd_roofline_pct.least_time_s(cfg, traffic, peaks, 1, chips=4)
     assert t == pytest.approx(5 * 512 ** 3 * 4 / 819e9 / 4, rel=1e-12)
     flops = 33 * 512 ** 3 * 64 / 197e12 / 4
@@ -71,7 +74,7 @@ def test_peaks_table_and_unknown_kind():
 
 
 def test_every_cell_resolves():
-    bench = _bench()
+    bench = cells.load_bench()
     for w in bench["workloads"]:
         cell = cells.load_cell(w["name"], bench)
         assert "setup_s" in cell.end_to_end
@@ -102,7 +105,7 @@ def _break(bench, root, what):
         t["entry"] = "no_such_entry"
         path.write_text(json.dumps(t))
     elif what in ("reference", "draw"):
-        cfg = _config(w["config"])
+        cfg = cells.load_config(w["config"])
         os.remove(root / "chipbench" / (
             f"reference/ops/{cfg['op']}.py" if what == "reference"
             else f"draws/{cfg['coefficients']['draw']}.py"))
@@ -117,7 +120,7 @@ def _break(bench, root, what):
                                   "metric", "reference", "draw"])
 def test_unresolved_name_fails_before_device_work(tmp_path, what):
     root = _copy_tree(tmp_path)
-    bench = _bench()
+    bench = cells.load_bench()
     name = _break(bench, root, what)
     with pytest.raises(cells.CellError):
         cells.load_cell(name, bench, root=str(root))
