@@ -11,10 +11,11 @@ space-time schedule (the per-row launch mode is kept for comparison):
     reads AND writes them through its (input-aliased) output refs, so no
     padded grid is ever materialized between diamond rows;
   * persistent VMEM scratch holds the live z-window of both parity buffers
-    (+ coefficient streams) for one extruded diamond tile; every step j
-    shifts the window down N_F z-rows ("pipelined" wavefront, Fig. 6c) and
-    DMAs the next slab of every stream HBM->VMEM;
-  * T = D_w/R in-tile time-step updates run at static z-offsets. Each
+    (+ coefficient streams) for one extruded diamond tile as a ring of z_ws
+    rows; every step j advances the ring by N_F z-rows ("pipelined"
+    wavefront, Fig. 6c) without moving any data, and DMAs the next slab of
+    every stream HBM->VMEM into the rows that fell off the window's top;
+  * T = D_w/R in-tile time-step updates run at static logical z-rows. Each
     computes only the sublane tiles that cover the diamond's y-range at
     that local time, masked to the range, and is skipped where the range
     is empty or its slab lies outside the interior (diamonds via masking:
@@ -34,13 +35,18 @@ crosses HBM once per D_w/(2R) time steps, in windows aligned to the
 accepts: y windows start and end on sublane-tile rows and cover the owned
 rows plus one tile either side, and x is padded to whole lanes. x shifts
 are lane rotations (`pltpu.roll`); y and z shifts are static offsets into
-the VMEM windows. The fused launch skips the inactive edge tiles that the
-per-row mode streams (repro/core/traffic.py counts both).
+the VMEM windows (in z, logical rows of the ring). The fused launch skips
+the inactive edge tiles that the per-row mode streams
+(repro/core/traffic.py counts both).
 
-Geometry (see DESIGN.md): update tau processes padded z-rows
-[N_F*j - (tau+1)R, N_F*(j+1) - (tau+1)R), i.e. buffer rows
-[R*(T-tau), R*(T-tau)+N_F); final-level rows leave through buffer rows
-[R, R+N_F) once j >= D_w/N_F.
+Geometry (see DESIGN.md), in logical window rows: update tau processes
+padded z-rows [N_F*j - (tau+1)R, N_F*(j+1) - (tau+1)R), i.e. window rows
+[R*(T-tau), R*(T-tau)+N_F); final-level rows leave through window rows
+[R, R+N_F) once j >= D_w/N_F. At step j logical row q lives in physical
+row (N_F*j + q) mod z_ws, so what step j writes at row q + N_F step j+1
+reads at row q where it lies. The relabelling keeps every read and write,
+and their order, of a window that moved: the in-place argument above holds
+unchanged.
 """
 
 from __future__ import annotations
@@ -233,11 +239,16 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
     not own are copied back unchanged: they hold what HBM held when the
     window loaded them, and no other tile runs in between.
 
+    The windows are rings of z_ws rows (module docstring, "Geometry"):
+    every access names logical window rows, and `ring` maps each plane to
+    its physical row at this step.
+
     Each phase of an active grid step runs under a `jax.named_scope`, which
     Mosaic lowers to a profiler region (``tpu.trace_start``/``trace_stop``):
-    ``mwd.shift``, ``mwd.fetch``, ``mwd.update`` and, on the steps that
-    emit, ``mwd.emit``. A TPU profile shows them (line ``XLA TraceMe``)
-    when libtpu runs with ``--xla_enable_custom_call_region_trace=true``.
+    ``mwd.shift`` (the ring's advance: the step's base row and fetch
+    rows), ``mwd.fetch``, ``mwd.update`` and, on the steps that emit,
+    ``mwd.emit``. A TPU profile shows them (line ``XLA TraceMe``) when
+    libtpu runs with ``--xla_enable_custom_call_region_trace=true``.
     """
     bounds_ref, p0_ref, ys_ref, y0_ref, y1_ref, act_ref = refs[:6]
     inputs = refs[6:6 + n_in]
@@ -255,8 +266,25 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
     tile = row * n_tiles + k
     ys = pl.multiple_of(ys_ref[tile], s)
     srcs = [out_e, out_o] + list(inputs[2:])
+    # Ring arithmetic on traced scalars uses lax directly: an operator on a
+    # traced scalar traces a jitted function, and these run for every level.
+    lax = jax.lax
 
-    def update_phase():
+    def ring(base, q: int):
+        """Physical row of logical window row q, the step's `base` given."""
+        t = lax.add(base, np.int32(q))          # base + q < 2 z_ws
+        return lax.select(lax.ge(t, np.int32(z_ws)),
+                          lax.sub(t, np.int32(z_ws)), t)
+
+    def copy(pairs, dma_sem):
+        """DMA each (src, dst) pair: start them all, then wait for each."""
+        cps = [pltpu.make_async_copy(a, b, dma_sem) for a, b in pairs]
+        for cp in cps:
+            cp.start()
+        for cp in cps:
+            cp.wait()
+
+    def update_phase(base, jz):
         """The in-tile updates of the levels with rows, and their masks.
 
         Level tau computes the `level_rows[tau]` window rows from the
@@ -265,7 +293,6 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
         masked write over the whole span would have stored there, so the
         result is unchanged.
         """
-        coeff_buf = bufs[2] if spec.n_coeff_arrays else None
         nxp = win.nxp
         # Dirichlet / shard-interior bounds, dynamic (padded coordinates)
         lo_z, hi_z = bounds_ref[0], bounds_ref[1]
@@ -276,24 +303,33 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
             return v if acc_dtype is None else v.astype(acc_dtype)
 
         def level(tau: int, rows: int, p: int, a, y_lo, y_hi, z_lo, z_hi):
-            zb = r * (t_steps - tau)        # buffer row of the N_F targets
+            zb = r * (t_steps - tau)        # window row of the N_F targets
             src_b, dst_b = bufs[p], bufs[1 - p]
-            cols = pl.ds(a, rows)
+            phys = {}                       # logical row -> physical row
             halo = {}                       # dz -> rows [a - s, a + rows + s)
+
+            def slab(b, c, y, n, lead=()):
+                """Logical rows [c, c + N_F) and y rows [y, y + n) of window
+                b[lead]: one plane per physical row, joined along z."""
+                for q in range(c, c + n_f):
+                    if q not in phys:
+                        phys[q] = ring(base, q)
+                return jnp.concatenate(
+                    [b[lead + (pl.ds(phys[q], 1), pl.ds(y, n))]
+                     for q in range(c, c + n_f)], axis=0)
 
             def tap(off):
                 dz, dy, dx = off
-                z = slice(zb + dz, zb + dz + n_f)
                 if dy:
                     # Mosaic loads dynamic sublane offsets only when
                     # aligned: load the aligned halo once per dz and shift
                     # by dy in registers
                     if dz not in halo:
-                        halo[dz] = src_b[z, pl.ds(a - s, rows + 2 * s)]
+                        halo[dz] = slab(src_b, zb + dz, a - s, rows + 2 * s)
                     v = jax.lax.slice_in_dim(halo[dz], s + dy, s + dy + rows,
                                              axis=1)
                 else:
-                    v = src_b[z, cols]
+                    v = slab(src_b, zb + dz, a, rows)
                 v = cast(v)
                 # x is never sliced at an offset: a lane rotation reads
                 # x + dx, wrapping only within R of the lane edges, which
@@ -303,12 +339,12 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
             def coeff(c):
                 if c.kind == "const":
                     return scalars[c.index]
-                return cast(coeff_buf[c.index, zb:zb + n_f, cols])
+                return cast(slab(bufs[2], zb, a, rows, (c.index,)))
 
-            old = dst_b[zb:zb + n_f, cols]
+            old = slab(dst_b, zb, a, rows)
             new = ir.update(spec, tap, coeff, lambda: cast(old))
             if acc_dtype is not None:
-                new = new.astype(dst_b.dtype)
+                new = new.astype(old.dtype)
 
             def iota(axis):
                 return jax.lax.broadcasted_iota(jnp.int32, old.shape, axis)
@@ -319,13 +355,12 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
             if n_f > 1:                     # a one-row slab is live in z
                 z_i = iota(0)
                 mask &= (z_i >= z_lo) & (z_i < z_hi)
-            dst_b[zb:zb + n_f, cols] = jax.lax.select(mask, new, old)
+            res = jax.lax.select(mask, new, old)
+            for i in range(n_f):
+                dst_b[pl.ds(phys[zb + i], 1), pl.ds(a, rows)] = res[i:i + 1]
 
-        # --- in-tile updates at static buffer offsets -------------------
-        # Scalar bounds use lax directly: an operator on a traced scalar
-        # traces a jitted function, and these run for every level.
-        lax = jax.lax
-        first, jz = tile * t_steps, j * n_f
+        # --- in-tile updates at static logical window rows ---------------
+        first = tile * t_steps
         y_lo0, y_hi0 = lax.sub(lo_y, ys), lax.sub(hi_y, ys)
         z_lo0 = lax.sub(lo_z, np.int32(n_f))
         levels = []
@@ -368,40 +403,36 @@ def _mwd_kernel(spec: st.StencilSpec, d_w: int, n_f: int, scalars,
             for b in bufs:
                 b[...] = jnp.zeros_like(b)
 
-        # --- shift the wavefront window down by N_F, stream next slabs in --
+        # --- advance the window ring by N_F, stream next slabs in --------
         with jax.named_scope("mwd.shift"):
-            for b in bufs:
-                if len(b.shape) == 3:
-                    b[0:z_ws - n_f] = b[n_f:z_ws]
-                else:
-                    b[:, 0:z_ws - n_f] = b[:, n_f:z_ws]
+            jz = lax.mul(j, np.int32(n_f))          # the slab's first plane
+            base = lax.rem(jz, np.int32(z_ws))      # physical row of row 0
+            # the rows that fell off the window's top take the new slab
+            slots = [ring(base, q) for q in range(z_ws - n_f, z_ws)]
         with jax.named_scope("mwd.fetch"):
+            # HBM plane jz + i of every stream lands in ring row slots[i]
+            zsrc = [pl.ds(lax.add(jz, np.int32(i)), 1) for i in range(n_f)]
             for src, dst in zip(srcs, bufs):
-                if len(dst.shape) == 3:   # solution window (scratch is 3-D)
-                    idx = bsel + (pl.ds(j * n_f, n_f), pl.ds(ys, wy))
-                    didx = (pl.ds(z_ws - n_f, n_f),)
-                else:                     # stacked coefficient window
-                    idx = bsel + (slice(None), pl.ds(j * n_f, n_f),
-                                  pl.ds(ys, wy))
-                    didx = (slice(None), pl.ds(z_ws - n_f, n_f))
-                cp = pltpu.make_async_copy(src.at[idx], dst.at[didx], sem)
-                cp.start()
-                cp.wait()
+                # the stacked coefficient window leads with its stream axis
+                lead = () if len(dst.shape) == 3 else (slice(None),)
+                copy([(src.at[bsel + lead + (z, pl.ds(ys, wy))],
+                       dst.at[lead + (pl.ds(slot, 1),)])
+                      for z, slot in zip(zsrc, slots)], sem)
         with jax.named_scope("mwd.update"):
-            update_phase()
+            update_phase(base, jz)
 
         # --- emit the completed slab (both parities) ----------------------
         @pl.when(j >= d_w // n_f)
         def _out():
             with jax.named_scope("mwd.emit"):
-                zs = j * n_f - d_w
+                # ring row done[i] goes to HBM plane jz - d_w + i
+                done = [ring(base, q) for q in range(r, r + n_f)]
+                zdst = [pl.ds(lax.add(jz, np.int32(i - d_w)), 1)
+                        for i in range(n_f)]
                 for out, b in ((out_e, bufs[0]), (out_o, bufs[1])):
-                    cp = pltpu.make_async_copy(
-                        b.at[pl.ds(r, n_f), pl.ds(s, span)],
-                        out.at[bsel + (pl.ds(zs, n_f), pl.ds(ys + s, span))],
-                        osem)
-                    cp.start()
-                    cp.wait()
+                    copy([(b.at[pl.ds(slot, 1), pl.ds(s, span)],
+                           out.at[bsel + (z, pl.ds(ys + s, span))])
+                          for z, slot in zip(zdst, done)], osem)
 
     if fused:
         # inactive edge tiles own no spans: skip their streams entirely

@@ -125,11 +125,17 @@ def _domain_oracle(spec, state, coeffs, t_steps, interior):
 # held by the level's guard alone. "batched" runs the case as entry 1 of a
 # two-grid launch; "domain" is the distributed stepper's call: the
 # tessellation spans the whole y extent and a tighter interior holds the
-# cells around it.
+# cells around it. "tall" runs on a grid TALL_NZ planes deep, where each
+# tile's window ring wraps many times; with N_F = 3, which divides no
+# z_ws = N_F + d_w + R here, slabs straddle the wrap.
+TALL_NZ = {1: 40, 4: 48}
+
+
 @pytest.mark.parametrize("name,k,n_f,t_steps,call", _mwd_cases(
     (4, 2, 5, "single"), (16, 1, 1, "single"), (16, 2, 3, "single"),
     (16, 1, 21, "single"), (14, 2, 5, "single"), (16, 1, 3, "batched"),
-    (14, 1, 5, "domain")))
+    (14, 1, 5, "domain"), (2, 1, 5, "tall"), (6, 3, 5, "tall"),
+    (2, 1, 5, "tall-batched")))
 def test_fused_mwd_matches_oracle_bitwise(name, k, n_f, t_steps, call):
     """The single-launch fused schedule == run_mwd oracle, both parities,
     all four corner-case stencils (interpret mode): bitwise for the
@@ -144,6 +150,11 @@ def test_fused_mwd_matches_oracle_bitwise(name, k, n_f, t_steps, call):
     r = spec.radius
     shape = (10, 20, 24) if r == 1 else (13, 21, 18)
     d_w = k * r
+    if call.startswith("tall"):
+        shape = (TALL_NZ[r],) + shape[1:]
+        geo = stencil_mwd.launch_geometry(r, d_w, n_f, shape, 4, t_steps)
+        assert geo.n_j > 2 * geo.win.z_ws / n_f      # wraps more than twice
+        assert n_f == 1 or geo.win.z_ws % n_f        # slabs straddle the wrap
     state, coeffs = st.make_problem(spec, shape, seed=11)
     if call == "domain":
         nz, ny, nx = shape
@@ -155,7 +166,7 @@ def test_fused_mwd_matches_oracle_bitwise(name, k, n_f, t_steps, call):
             interior=b, y_domain=(0, ny)))(jnp.asarray(interior, jnp.int32))
     else:
         want = mwd.run_mwd(spec, state, coeffs, t_steps, mwd.MWDPlan(d_w=d_w))
-        if call == "batched":
+        if call.endswith("batched"):
             other, other_c = st.make_problem(spec, shape, seed=13)
             got = ops.mwd_batched(spec, [other, state], [other_c, coeffs],
                                   t_steps, d_w=d_w, n_f=n_f)
@@ -180,7 +191,7 @@ def test_fused_mwd_matches_oracle_bitwise(name, k, n_f, t_steps, call):
 
 
 @pytest.mark.parametrize("name,k,t_steps", _mwd_cases(
-    (2, 4), (16, 3), (14, 9)))
+    (2, 4), (16, 3), (14, 9), (2, 3)))
 def test_fused_equals_per_row_launches(name, k, t_steps):
     """One launch for the whole schedule == one launch per diamond row."""
     import numpy as np

@@ -172,3 +172,23 @@ def test_mwd_kernel_has_balanced_phase_regions(name, one_chip, mosaic):
         depth += 1 if kind == "start" else -1
         assert depth in (0, 1)        # regions neither nest nor overlap
     assert depth == 0
+
+
+@pytest.mark.parametrize("n_f", [1, 2])
+@pytest.mark.parametrize("name", ["7pt-var", "25pt-const"])
+def test_mwd_shift_region_moves_no_vectors(name, n_f, one_chip, mosaic):
+    """The window advance is ring arithmetic: `mwd.shift` loads and stores
+    nothing, so no grid step copies a VMEM window."""
+    spec = st.SPECS[name]
+    state, arrays, scalars = _operands(spec, FORWARD[name], one_chip)
+    plan = MWDPlan(d_w=PLAN.d_w, n_f=n_f)
+
+    def fwd(state, arrays):
+        return ops.mwd(spec, state, ir.join_coeffs(spec, arrays, scalars),
+                       16, plan=plan)
+
+    (text,) = _mosaic_texts(jax.jit(fwd).lower(state, arrays))
+    start = text.index('message = "mwd.shift"')
+    body = text[start:text.index("tpu.trace_stop", start)]
+    assert not re.search(r"\b(vector|tpu)\.\w*(load|store)", body)
+    assert "arith.remsi" in body          # the region holds the ring's base
